@@ -438,14 +438,13 @@ def _scan_driver():
     return run
 
 
-def run_tables(tables: BatchTables, *, kernel: str = "ref",
-               interpret: bool = True):
+def run_tables(tables: BatchTables, *, kernel: str = "ref"):
     """Advance the whole grid; returns ``(nw_final, fs_final, agg)``
     as numpy.
 
-    ``kernel="ref"``: jitted scan of the pure-jnp step (fast on CPU).
+    ``kernel="ref"``: jitted scan of the pure-jnp step.
     ``kernel="pallas"``: the chunked-time Pallas kernel from
-    ``repro.kernels.cluster_step`` (``interpret=True`` on CPU).
+    ``repro.kernels.cluster_step`` (interpreted on the CPU backend only).
     """
     import jax.numpy as jnp
 
@@ -454,7 +453,7 @@ def run_tables(tables: BatchTables, *, kernel: str = "ref",
             tables.ntier, tables.frac, tables.scal)
     if kernel == "pallas":
         from repro.kernels.cluster_step import cluster_sim_pallas
-        nw, fs, _, agg = cluster_sim_pallas(*args, interpret=interpret)
+        nw, fs, _, agg = cluster_sim_pallas(*args)
     elif kernel == "ref":
         now_t = jnp.arange(tables.arrivals.shape[1],
                            dtype=jnp.float32) * tables.dt
@@ -539,8 +538,7 @@ def ledgers_from_agg(tables: BatchTables, nw: np.ndarray, fs: np.ndarray,
 
 def simulate_batch(scenarios: Sequence, *, dt: float = DEFAULT_DT,
                    kernel: str = "ref", cost_model=None,
-                   trace_fn: Optional[Callable] = None,
-                   interpret: bool = True) -> List[BatchLedger]:
+                   trace_fn: Optional[Callable] = None) -> List[BatchLedger]:
     """Run every scenario as one batched JAX program; one
     :class:`BatchLedger` per cell, in input order."""
     for sc in scenarios:
@@ -551,7 +549,7 @@ def simulate_batch(scenarios: Sequence, *, dt: float = DEFAULT_DT,
                 "run topology scenarios under driver='sim' or 'fleet'")
     tables = build_tables(scenarios, dt=dt, cost_model=cost_model,
                           trace_fn=trace_fn)
-    nw, fs, agg = run_tables(tables, kernel=kernel, interpret=interpret)
+    nw, fs, agg = run_tables(tables, kernel=kernel)
     return ledgers_from_agg(tables, nw, fs, agg)
 
 

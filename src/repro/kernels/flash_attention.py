@@ -2,8 +2,10 @@
 
 TPU adaptation notes (vs the canonical CUDA flash kernel):
   * tiles live in VMEM via explicit ``BlockSpec``s — (block_q, head_dim) and
-    (block_k, head_dim) tiles sized so q/k/v/acc fit the ~16 MiB VMEM budget
-    with MXU-aligned (multiple-of-128) matmul dims;
+    (block_k, head_dim) tiles of a heads-major (B, H, S, D) copy, so each
+    block's last two dims are the TPU tile's (sublane, lane) pair — sized
+    so q/k/v/acc fit the ~16 MiB VMEM budget with MXU-aligned
+    (multiple-of-128) matmul dims;
   * the KV loop is the innermost *grid* dimension (TPU grids execute
     sequentially per core), with the online-softmax state (m, l, acc) carried
     in VMEM scratch across grid steps — no warp shuffles / shared-memory
@@ -25,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_on_this_platform
+
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -41,34 +45,35 @@ def _attn_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale       # (bq, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (bk, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = q @ k.T                                             # (bq, bk) on MXU
+    q = q_ref[0, 0].astype(jnp.float32) * scale             # (bq, d)
+    k = k_ref[0, 0].astype(jnp.float32)                     # (bk, d)
+    v = v_ref[0, 0].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),  # q @ k.T on MXU
+                            preferred_element_type=jnp.float32)
 
-    qp = qpos_ref[...]                                       # (bq,)
-    kp = kpos_ref[...]                                       # (bk,)
-    mask = jnp.ones_like(s, dtype=jnp.bool_)
+    qp = qpos_ref[0]                                         # (bq, 1)
+    kp = kpos_ref[0]                                         # (1, bk)
+    mask = jnp.ones(s.shape, dtype=jnp.bool_)
     if causal:
-        mask &= kp[None, :] <= qp[:, None]
+        mask &= kp <= qp
     if window is not None:
-        mask &= kp[None, :] > (qp[:, None] - window)
+        mask &= kp > (qp - window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
+    m_prev = m_ref[...]                                      # (bq, 1)
     l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+    l_ref[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
-    l_ref[...] = l_new
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -78,8 +83,13 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            q_pos=None, kv_pos=None,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True):
-    """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
+                           interpret: Optional[bool] = None):
+    """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
+
+    ``interpret=None`` runs natively on an accelerator and through the
+    Pallas interpreter on the CPU."""
+    if interpret is None:
+        interpret = interpret_on_this_platform()
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -90,31 +100,39 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         q_pos = jnp.arange(sq) + (skv - sq)
     if kv_pos is None:
         kv_pos = jnp.arange(skv)
-    q_pos = q_pos.astype(jnp.int32)
-    kv_pos = kv_pos.astype(jnp.int32)
     nq, nk = sq // bq, skv // bk
+    # positions split per block into a column and a row, so each block's
+    # last two dims, (bq, 1) and (1, bk), are the whole of the array's
+    q_pos = q_pos.astype(jnp.int32).reshape(nq, bq, 1)
+    kv_pos = kv_pos.astype(jnp.int32).reshape(nk, 1, bk)
     grid = (b, hq, nq, nk)
 
     kernel = functools.partial(
         _attn_kernel, causal=causal, window=window, num_kv_blocks=nk,
         scale=1.0 / (d ** 0.5))
 
-    return pl.pallas_call(
+    # heads-major layout: every tile is a (block, head_dim) slab
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq,), lambda bi, h, qi, ki: (qi,)),        # q_pos
-            pl.BlockSpec((bk,), lambda bi, h, qi, ki: (ki,)),        # kv_pos
-            pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // g, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bi, h, qi, ki: (qi, 0, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bi, h, qi, ki: (ki, 0, 0)),
+            pl.BlockSpec((1, 1, bq, d), lambda bi, h, qi, ki: (bi, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, h, qi, ki: (bi, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, h, qi, ki: (bi, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, d),
+                               lambda bi, h, qi, ki: (bi, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),       # m (running max)
-            pltpu.VMEM((bq,), jnp.float32),       # l (running denom)
+            pltpu.VMEM((bq, 1), jnp.float32),     # m (running max)
+            pltpu.VMEM((bq, 1), jnp.float32),     # l (running denom)
             pltpu.VMEM((bq, d), jnp.float32),     # acc
         ],
         interpret=interpret,
-    )(q_pos, kv_pos, q, k, v)
+    )(q_pos, kv_pos, qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

@@ -8,7 +8,8 @@ Each op has three execution paths:
   O(S^2) score tensor), and what runs in CPU tests/benchmarks.
 * ``impl="pallas"`` — the TPU Pallas kernels (``flash_attention.py``,
   ``decode_attention.py``, ``ssm_scan.py``) with explicit BlockSpec VMEM
-  tiling; validated on CPU via ``interpret=True``.
+  tiling.  They compile natively on an accelerator and run through the
+  Pallas interpreter only on the CPU backend (tests).
 * ``impl="oracle"`` — the naive oracles in ``ref.py`` (tests only).
 
 All paths agree to numerical tolerance; see ``tests/test_kernels.py``.

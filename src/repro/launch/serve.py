@@ -5,7 +5,8 @@
 
 Registers the arch as a 'function', drives a request sequence through the
 router (cold starts are genuinely measured: XLA compile + weight load),
-prints the QoS summary.
+prints the QoS summary.  Models are served at their published widths on
+the accelerator; ``--smoke`` serves the reduced config (CPU drives).
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.metrics import format_summary
 from repro.serving.router import FunctionDef, ServerlessRouter
 
@@ -27,14 +29,18 @@ def main():
     ap.add_argument("--no-snapshots", action="store_true")
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--decode-steps", type=int, default=8)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced config (2 layers, float32)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     archs = args.arch if isinstance(args.arch, list) else [args.arch]
     router = ServerlessRouter(ttl_s=args.ttl,
                               use_snapshots=not args.no_snapshots)
     for a in archs:
         router.register(FunctionDef(a, a, max_seq=args.seq,
-                                    decode_steps=args.decode_steps))
+                                    decode_steps=args.decode_steps,
+                                    smoke=args.smoke))
     rng = np.random.default_rng(0)
     for i in range(args.requests):
         name = archs[i % len(archs)]

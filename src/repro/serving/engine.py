@@ -12,8 +12,9 @@ model endpoint, and its cold start is genuinely paid here —
 
 Mitigation paths implemented for real:
   * snapshot/restore (vHive/Catalyzer): params serialized to an .npz
-    snapshot + compiled executables kept in a process-level cache keyed by
-    (arch, shapes) — a restore pays deserialization + device_put only;
+    snapshot (the ``training/checkpoint.py`` format) + compiled executables
+    kept in a process-level cache keyed by (arch, shapes) — a restore pays
+    deserialization + device_put only;
   * keep-warm / scale-to-zero: ``shutdown()`` drops device state; the
     frontend (router.py) applies TTL policies over engines;
   * fusion: ``fuse_chain`` compiles a chained two-stage pipeline as ONE
@@ -23,9 +24,8 @@ All timings are wall-clock measured (perf_counter + block_until_ready).
 """
 from __future__ import annotations
 
-import io
 import os
-import pickle
+import tempfile
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.lifecycle import Breakdown, Phase
 from repro.models import registry
+from repro.training import checkpoint
 
 
 def _tree_bytes(tree) -> int:
@@ -76,9 +77,10 @@ class SnapshotStore:
     the pre-baked memory image.
     """
 
-    def __init__(self, root: str = "/tmp/coldjax_snapshots"):
-        self.root = root
-        os.makedirs(root, exist_ok=True)
+    def __init__(self, root: Optional[str] = None):
+        self.root = root or os.path.join(tempfile.gettempdir(),
+                                         "coldjax_snapshots")
+        os.makedirs(self.root, exist_ok=True)
         self.executables: Dict[str, Any] = {}
 
     # params ------------------------------------------------------------- #
@@ -89,19 +91,10 @@ class SnapshotStore:
         return os.path.exists(self._path(key))
 
     def save_params(self, key: str, params) -> int:
-        leaves, treedef = jax.tree.flatten(params)
-        arrs = {f"a{i}": np.asarray(x) for i, x in enumerate(leaves)}
-        with open(self._path(key), "wb") as f:
-            np.savez(f, __treedef__=np.frombuffer(
-                pickle.dumps(treedef), dtype=np.uint8), **arrs)
-        return os.path.getsize(self._path(key))
+        return checkpoint.save(self._path(key), params)
 
     def load_params(self, key: str):
-        with np.load(self._path(key), allow_pickle=False) as z:
-            treedef = pickle.loads(z["__treedef__"].tobytes())
-            n = len(z.files) - 1
-            leaves = [jnp.asarray(z[f"a{i}"]) for i in range(n)]
-        return jax.tree.unflatten(treedef, leaves)
+        return checkpoint.restore(self._path(key))[0]
 
     # executables ---------------------------------------------------------- #
     def get_executable(self, key: str):
@@ -121,6 +114,15 @@ class ServeStats:
     prefill_s: float = 0.0
     decode_s: float = 0.0
     tokens: int = 0
+    logits: Optional[np.ndarray] = None   # last decode step's (B, V) logits
+
+
+@dataclass(frozen=True)
+class StartPath:
+    """Which mechanisms a cold start used."""
+
+    from_snapshot: bool      # params restored from the SnapshotStore
+    executable_hit: bool     # compiled programs found in the store
 
 
 class InferenceEngine:
@@ -142,6 +144,7 @@ class InferenceEngine:
         self._decode_c = None
         self.warm = False
         self.last_breakdown: Optional[Breakdown] = None
+        self.last_start: Optional[StartPath] = None
         self.last_used = 0.0
 
     # ------------------------------------------------------------------ #
@@ -180,11 +183,18 @@ class InferenceEngine:
             if use_snap:
                 self.params = self.store.load_params(self.key)
             else:
-                self.params = self.bundle.init(jax.random.key(self.seed))
+                # one jitted program on the device, so no float32
+                # temporaries per leaf; an RngBitGenerator key because a
+                # threefry init of full-width granite takes ~22 s to
+                # compile for a v5e, rbg ~6 s
+                self.params = jax.jit(self.bundle.init)(
+                    jax.random.key(self.seed, impl="rbg"))
             jax.block_until_ready(self.params)
         with t.phase(Phase.CODE_INIT):
             exe = None if self.store is None else \
                 self.store.get_executable(self.key)
+            self.last_start = StartPath(from_snapshot=use_snap,
+                                        executable_hit=exe is not None)
             if exe is not None:
                 self._prefill_c, self._decode_c = exe
             else:
@@ -240,6 +250,7 @@ class InferenceEngine:
         jax.block_until_ready(tok)
         stats.decode_s = time.perf_counter() - t0
         stats.tokens = decode_steps
+        stats.logits = np.asarray(logits)
         self.last_used = time.monotonic()
         return np.stack(out, axis=1), stats
 

@@ -38,6 +38,7 @@ class FunctionDef:
     batch: int = 1
     memory_gb: float = 0.5
     decode_steps: int = 4
+    smoke: bool = True       # reduced config; False serves published widths
 
 
 class ServerlessRouter:
@@ -73,7 +74,7 @@ class ServerlessRouter:
             memory_mb=fdef.memory_gb * 1024.0, arch=fdef.arch)
         self.backend.profiles[fdef.name] = EngineProfile(
             arch=fdef.arch, max_seq=fdef.max_seq, batch=fdef.batch,
-            decode_steps=fdef.decode_steps)
+            decode_steps=fdef.decode_steps, smoke=fdef.smoke)
 
     def _now(self) -> float:
         now = time.monotonic() - self._t0

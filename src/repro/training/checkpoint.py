@@ -1,36 +1,109 @@
-"""Checkpointing: flattened-pytree .npz save/restore (numpy only).
+"""Checkpoints: one pickle-free .npz format for params pytrees.
 
-The checkpoint doubles as the serving snapshot format (SnapshotStore uses
-the same layout) — a trained model's checkpoint IS its pre-baked cold-start
-image, closing the loop between the training and serving halves.
+The checkpoint doubles as the serving snapshot format (SnapshotStore calls
+:func:`save` and :func:`restore`) — a trained model's checkpoint IS its
+pre-baked cold-start image, closing the loop between the training and
+serving halves.
+
+Layout: every leaf is an .npz member named by its flattened pytree path
+(``blocks/0/attn/wq``).  A JSON manifest (``__manifest__``) lists each
+leaf's path, as dict keys (str) and list indices (int), and its dtype
+name, so dtypes numpy cannot name (bfloat16 would come back as ``|V2``)
+round-trip through their raw bits.  ``extra`` is stored in the manifest
+as JSON.  Nothing is pickled, so a file outlives the JAX version that
+wrote it.  Trees are nested dicts (str keys) and lists of arrays.
 """
 from __future__ import annotations
 
+import json
 import os
-import pickle
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import DictKey, SequenceKey
+
+FORMAT = 1
+_MANIFEST = "__manifest__"
+
+PathEntry = Union[str, int]
+
+
+def _path_entries(path) -> List[PathEntry]:
+    out: List[PathEntry] = []
+    for k in path:
+        if isinstance(k, DictKey) and isinstance(k.key, str):
+            out.append(k.key)
+        elif isinstance(k, SequenceKey):
+            out.append(k.idx)
+        else:
+            raise TypeError(f"checkpoint trees hold str-keyed dicts and "
+                            f"lists only; got path entry {k!r}")
+    return out
+
+
+def _storable(a: np.ndarray) -> np.ndarray:
+    """``a`` with a dtype numpy can write: ml_dtypes (bfloat16, fp8) are
+    stored as their raw bits."""
+    if a.dtype.kind == "V":
+        return a.view(np.dtype(f"u{a.dtype.itemsize}"))
+    return a
 
 
 def save(path: str, params: Any, *, extra: Optional[dict] = None) -> int:
+    """Write ``params`` (and JSON-able ``extra``) to ``path``; returns the
+    file size in bytes."""
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    leaves, treedef = jax.tree.flatten(params)
-    arrs = {f"a{i}": np.asarray(x) for i, x in enumerate(leaves)}
-    meta = {"treedef": treedef, "extra": extra or {}}
+    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
+    arrs: Dict[str, np.ndarray] = {}
+    manifest = {"format": FORMAT, "extra": extra or {}, "leaves": []}
+    for p, x in leaves:
+        entries = _path_entries(p)
+        a = np.asarray(x)
+        arrs["/".join(map(str, entries))] = _storable(a)
+        manifest["leaves"].append({"path": entries, "dtype": a.dtype.name})
+    blob = np.frombuffer(json.dumps(manifest).encode(), np.uint8)
     with open(path, "wb") as f:
-        np.savez(f, __meta__=np.frombuffer(pickle.dumps(meta), np.uint8), **arrs)
+        np.savez(f, **{_MANIFEST: blob}, **arrs)
     return os.path.getsize(path)
 
 
+def _insert(tree: Any, entries: List[PathEntry], leaf) -> Any:
+    if not entries:
+        return leaf
+    head, rest = entries[0], entries[1:]
+    if tree is None:
+        tree = [] if isinstance(head, int) else {}
+    if isinstance(head, int):
+        while len(tree) <= head:
+            tree.append(None)
+    else:
+        tree.setdefault(head, None)
+    tree[head] = _insert(tree[head], rest, leaf)
+    return tree
+
+
 def restore(path: str) -> Tuple[Any, dict]:
+    """Read a checkpoint written by :func:`save`; returns
+    ``(params, extra)`` with the leaves put on the default device in one
+    transfer."""
     with np.load(path, allow_pickle=False) as z:
-        meta = pickle.loads(z["__meta__"].tobytes())
-        n = len(z.files) - 1
-        leaves = [jnp.asarray(z[f"a{i}"]) for i in range(n)]
-    return jax.tree.unflatten(meta["treedef"], leaves), meta["extra"]
+        manifest = json.loads(z[_MANIFEST].tobytes().decode())
+        if manifest.get("format") != FORMAT:
+            raise ValueError(f"{path}: checkpoint format "
+                             f"{manifest.get('format')!r} != {FORMAT}")
+        specs = manifest["leaves"]
+        leaves = []
+        for spec in specs:
+            a = z["/".join(map(str, spec["path"]))]
+            want = jnp.dtype(spec["dtype"])
+            leaves.append(a if a.dtype == want else a.view(want))
+    leaves = jax.device_put(leaves)
+    tree = None
+    for spec, leaf in zip(specs, leaves):
+        tree = _insert(tree, spec["path"], leaf)
+    return tree, manifest["extra"]
 
 
 def tree_equal(a: Any, b: Any) -> bool:

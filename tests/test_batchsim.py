@@ -1,8 +1,8 @@
-"""Batch-driver tests: Pallas-vs-ref kernel parity (randomized fixtures,
-``interpret=True``), batch-vs-scalar tolerance spot-checks, unsupported
--policy rejection, the runner's batch plumbing (summary schema, events=
-rejection, ``max_cells`` guard, progress callbacks), and the trace-cache
-LRU regression."""
+"""Batch-driver tests: Pallas-vs-ref kernel parity (randomized fixtures;
+interpreted on the CPU, native on a TPU), batch-vs-scalar tolerance
+spot-checks, unsupported-policy rejection, the runner's batch plumbing
+(summary schema, events= rejection, ``max_cells`` guard, progress
+callbacks), and the trace-cache LRU regression."""
 import numpy as np
 import pytest
 
@@ -92,8 +92,7 @@ def test_pallas_matches_ref_on_random_state(seed):
     ref = list(_ref_drive(nw, fs, free, arrivals, conc, fparam, promote,
                           dwell, ntier, frac, scal))
     pal = cluster_sim_pallas(nw, fs, free, arrivals, conc, fparam, promote,
-                             dwell, ntier, frac, scal, chunk=8,
-                             interpret=True)
+                             dwell, ntier, frac, scal, chunk=8)
     for name, a, b in zip(("nw", "fs", "free", "agg"), ref, pal):
         np.testing.assert_allclose(np.asarray(b), a, rtol=1e-4, atol=1e-2,
                                    err_msg=f"pallas/{name} diverged")

@@ -1,6 +1,7 @@
-"""Kernel validation: Pallas (interpret=True) and the memory-bounded jnp
-paths vs the naive oracles in ``kernels/ref.py`` — shape/dtype sweeps with
-assert_allclose (assignment requirement)."""
+"""Kernel validation: Pallas (interpreted on the CPU, native on a TPU)
+and the memory-bounded jnp paths vs the naive oracles in
+``kernels/ref.py`` — shape/dtype sweeps with assert_allclose (assignment
+requirement)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

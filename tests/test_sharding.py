@@ -2,6 +2,8 @@
 coverage, collective-parse sanity, and a true multi-device jit in a
 subprocess (XLA_FLAGS must not leak into this process)."""
 import json
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -77,9 +79,10 @@ from repro import sharding
 from repro.config import get_config, reduced, InputShape
 from repro.models import registry
 from repro.launch import specs as S
+from repro.launch.mesh import make_mesh
 
 # tiny mesh exercising the same code path: (data=2, model=4)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = reduced(get_config("qwen3-moe-30b-a3b"), d_model=256)
 shape = InputShape("t", 32, 4, "train")
 rules = sharding.make_rules(cfg, shape, mesh)
@@ -100,11 +103,17 @@ print(json.dumps({"sharded": float(loss), "unsharded": float(loss1)}))
 
 def test_sharded_execution_matches_unsharded():
     """Run the MoE model under a real 8-device (2x4) mesh in a subprocess;
-    the sharded loss must equal the single-device loss."""
+    the sharded loss must equal the single-device loss.  The child stays
+    on the CPU: a scrubbed environment would let it reach for the TPU
+    runtime that another test process may hold."""
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = {"PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": os.environ.get("PATH", "/usr/bin:/bin")}
+    if "HOME" in os.environ:
+        env["HOME"] = os.environ["HOME"]
     res = subprocess.run(
         [sys.executable, "-c", SUBPROCESS_SCRIPT], capture_output=True,
-        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                        "HOME": "/root"}, cwd="/root/repo", timeout=500)
+        text=True, env=env, cwd=repo, timeout=500)
     assert res.returncode == 0, res.stderr[-2000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert abs(out["sharded"] - out["unsharded"]) < 2e-3, out
