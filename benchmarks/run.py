@@ -25,7 +25,6 @@ values no longer need ``* 1e8``-style scale hacks — rows default to
   bench_topology   edge–cloud offloading Pareto sweep: greedy/probabilistic
                    routing vs always_local/always_cloud baselines
                    (writes BENCH_topology.json)
-  bench_roofline   dry-run/roofline summary (deliverables e+g)
 
 The simulated modules are thin declarations over the scenario registry
 (``repro.experiments``); run any cell directly with
@@ -45,8 +44,8 @@ import traceback
 
 from benchmarks import (bench_batchsim, bench_csf, bench_csl, bench_factors,
                         bench_fleet, bench_learn, bench_platforms, bench_qos,
-                        bench_roofline, bench_serving, bench_simcore,
-                        bench_tiers, bench_topology, bench_tradeoffs)
+                        bench_serving, bench_simcore, bench_tiers,
+                        bench_topology, bench_tradeoffs)
 from benchmarks.emit import csv_emit
 
 MODULES = [
@@ -63,7 +62,6 @@ MODULES = [
     ("batchsim", bench_batchsim),
     ("learn", bench_learn),
     ("topology", bench_topology),
-    ("roofline", bench_roofline),
 ]
 
 

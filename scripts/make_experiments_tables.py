@@ -1,8 +1,7 @@
 """Render the EXPERIMENTS.md tables from the sweep JSONs.
 
 Modes:
-  dryrun / roofline   the launch-plane sweeps (dryrun_results.json /
-                      roofline_results.json)
+  dryrun              the launch-plane dry-run sweep (dryrun_results.json)
   scenarios PATH      rows written by ``python -m repro.experiments
                       run/sweep --json PATH`` — the scenario registry's
                       machine-readable output (no stdout scraping)
@@ -30,36 +29,6 @@ def dryrun_table(recs, mesh):
                 f"{r['collective_bytes_total'] / 2**30:.2f} |")
         else:
             out.append(f"| {r['arch']} | {r['shape']} | {r['status']} | — | — | — | — |")
-    return "\n".join(out)
-
-
-def roofline_table(recs, base=None):
-    basemap = {}
-    if base:
-        basemap = {(r["arch"], r["shape"]): r for r in base
-                   if r.get("status") == "ok"}
-    out = ["| arch | shape | compute_s | memory_s | collective_s | dominant | "
-           "useful | MFU bound | baseline bound | Δ |",
-           "|---|---|---|---|---|---|---|---|---|---|"]
-    for r in recs:
-        if r.get("status") != "ok":
-            if r.get("status") == "skipped":
-                out.append(f"| {r['arch']} | {r['shape']} | skipped (long_500k "
-                           "needs sub-quadratic attention) | | | | | | | |")
-            continue
-        bound = max(r["compute_s"], r["memory_s"], r["collective_s"])
-        b = basemap.get((r["arch"], r["shape"]))
-        if b:
-            bb = max(b["compute_s"], b["memory_s"], b["collective_s"])
-            delta = f"{bb / bound:.1f}x" if bound > 0 else "—"
-            bbs = f"{bb:.3f}"
-        else:
-            bbs, delta = "—", "—"
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {r['compute_s']:.3f} | "
-            f"{r['memory_s']:.3f} | {r['collective_s']:.4f} | {r['dominant']} | "
-            f"{r['useful_flops_ratio']:.2f} | {r['mfu_upper_bound']:.4f} | "
-            f"{bbs} | {delta} |")
     return "\n".join(out)
 
 
@@ -111,10 +80,3 @@ if __name__ == "__main__":
         print(dryrun_table(recs, "16x16"))
         print("\n### multi-pod (2×16×16 = 512 chips)\n")
         print(dryrun_table(recs, "2x16x16"))
-    elif which == "roofline":
-        recs = load("roofline_results.json")
-        try:
-            base = load("roofline_results_baseline.json")
-        except FileNotFoundError:
-            base = None
-        print(roofline_table(recs, base))

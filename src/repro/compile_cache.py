@@ -7,10 +7,14 @@ compile are never served from disk.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import pathlib
+from typing import Dict, Iterator
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
+EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+          "/jax/compilation_cache/cache_misses": "cache_misses"}
 DEFAULT_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
@@ -28,3 +32,23 @@ def enable() -> str:
 
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     return str(DEFAULT_DIR)
+
+
+@contextlib.contextmanager
+def counting() -> Iterator[Dict[str, int]]:
+    """Count the persistent cache's hits and misses (a miss is an entry
+    written) while entered, from JAX's monitoring events; yields the dict
+    it fills, ``{"cache_hits": n, "cache_misses": m}``."""
+    import jax.monitoring
+
+    counts = dict.fromkeys(EVENTS.values(), 0)
+
+    def listen(event: str, **kwargs) -> None:
+        if event in EVENTS:
+            counts[EVENTS[event]] += 1
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        yield counts
+    finally:
+        jax.monitoring.unregister_event_listener(listen)

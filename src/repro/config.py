@@ -109,10 +109,8 @@ class ModelConfig:
     # execution --------------------------------------------------------------- #
     attention_impl: str = "reference"   # reference | pallas
     remat: bool = True              # activation checkpointing in train_step
-    unroll_layers: bool = False     # roofline analysis: materialise the layer
-                                    # loop so cost_analysis counts every layer
-    full_param_count: int = 0       # set by roofline's scaled variants so
-                                    # sharding guards see the real model size
+    unroll_layers: bool = False     # materialise the layer loop so
+                                    # cost_analysis counts every layer
 
     # ------------------------------------------------------------------ #
     def __post_init__(self):

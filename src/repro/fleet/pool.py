@@ -27,12 +27,12 @@ modeled).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import spans
 from repro.core.cluster import ClusterState, scale_breakdown
 from repro.core.costmodel import CostModel
 from repro.core.events import EventLog
@@ -238,17 +238,16 @@ class EngineBackend(ExecutionBackend):
         calls = max(1, -(-len(requests) // prof.batch))
         total = 0.0
         for _ in range(calls):
-            _, duration = self.serve(replica, tokens,
-                                     decode_steps=prof.decode_steps)
-            total += duration
+            _, stats = self.serve(replica, tokens,
+                                  decode_steps=prof.decode_steps)
+            total += stats.run_s
         return total
 
     def serve(self, replica: Replica, tokens: np.ndarray, *,
-              decode_steps: int = 4, extras=None) -> Tuple[np.ndarray, float]:
-        t0 = time.perf_counter()
-        out, _ = replica.engine.serve(tokens, decode_steps=decode_steps,
-                                      extras=extras)
-        return out, time.perf_counter() - t0
+              decode_steps: int = 4, extras=None):
+        """The engine's tokens and its ``ServeStats``."""
+        return replica.engine.serve(tokens, decode_steps=decode_steps,
+                                    extras=extras)
 
     def release(self, replica: Replica) -> None:
         if replica.engine is not None:
@@ -356,16 +355,17 @@ class EnginePool:
         if tier is None:
             tier = (WarmthTier.SNAPSHOT_READY if from_snapshot
                     else WarmthTier.DEAD)
-        c = self.state.admit(function, worker, now,
-                             has_snapshot=tier == WarmthTier.SNAPSHOT_READY,
-                             tier=tier)
-        replica = Replica(container=c, spec=self.state.functions[function])
-        self.replicas[c.id] = replica
-        bd = self.backend.provision(
-            replica, tier=tier,
-            concurrent_colds=self.state.provisioning_on(worker) - 1,
-            deps_fraction=deps_fraction, from_pause_pool=from_pause_pool,
-            speed=self.state.speed(worker))
+        with spans.span("pool.start", function=function, tier=tier.name):
+            c = self.state.admit(function, worker, now,
+                                 has_snapshot=tier == WarmthTier.SNAPSHOT_READY,
+                                 tier=tier)
+            replica = Replica(container=c, spec=self.state.functions[function])
+            self.replicas[c.id] = replica
+            bd = self.backend.provision(
+                replica, tier=tier,
+                concurrent_colds=self.state.provisioning_on(worker) - 1,
+                deps_fraction=deps_fraction, from_pause_pool=from_pause_pool,
+                speed=self.state.speed(worker))
         self.phase_log.append(bd)
         return replica, bd
 

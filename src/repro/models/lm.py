@@ -37,17 +37,25 @@ def _embed_tokens(p, cfg, tokens):
 
 def _inputs_to_x(p, cfg, batch):
     """tokens (+ optional image embeds prepended) -> (B, S, d)."""
-    x = _embed_tokens(p, cfg, batch["tokens"])
-    if cfg.vision is not None and "image_embeds" in batch:
-        img = batch["image_embeds"].astype(x.dtype) @ p["proj"]
-        x = jnp.concatenate([img, x[:, : x.shape[1] - img.shape[1], :]], axis=1)
+    with jax.named_scope("embed"):
+        x = _embed_tokens(p, cfg, batch["tokens"])
+        if cfg.vision is not None and "image_embeds" in batch:
+            img = batch["image_embeds"].astype(x.dtype) @ p["proj"]
+            x = jnp.concatenate([img, x[:, : x.shape[1] - img.shape[1], :]],
+                                axis=1)
     return sharding.logical(x, ("batch", "seq", "embed"))
 
 
 def _unembed(p, cfg, x):
     w = p["embed"].T if cfg.tie_embeddings else p["unembed"]
-    logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
+    with jax.named_scope("unembed"):
+        logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
     return sharding.logical(logits, ("batch", None, "vocab"))
+
+
+def _final_norm(p, cfg, x):
+    with jax.named_scope("norm"):
+        return layers.norm_apply(p["norm_f"], x, cfg.norm)
 
 
 def lm_forward(p, cfg, batch, *, window=None, train=False):
@@ -57,7 +65,7 @@ def lm_forward(p, cfg, batch, *, window=None, train=False):
     q_pos = jnp.arange(s)
     x, aux, caches = transformer.stack_full(p["blocks"], x, cfg, q_pos=q_pos,
                                             window=window, train=train)
-    x = layers.norm_apply(p["norm_f"], x, cfg.norm)
+    x = _final_norm(p, cfg, x)
     return _unembed(p, cfg, x), aux, caches
 
 
@@ -129,9 +137,10 @@ def _format_caches(cfg, raw_caches, *, seq_len: int, max_seq: int, window):
 
 def lm_decode_step(p, cfg, caches, token, pos, *, window=None):
     """token: (B,) int32; pos: scalar int32.  Returns (logits (B, V), caches)."""
-    x = jnp.take(p["embed"], token, axis=0).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("embed"):
+        x = jnp.take(p["embed"], token, axis=0).astype(jnp.dtype(cfg.dtype))
     x, caches = transformer.stack_decode(p["blocks"], x, cfg, pos=pos,
                                          window=window, caches=caches)
-    x = layers.norm_apply(p["norm_f"], x, cfg.norm)
+    x = _final_norm(p, cfg, x)
     logits = _unembed(p, cfg, x[:, None, :])[:, 0, :]
     return logits, caches

@@ -77,23 +77,36 @@ def build(cfg: ModelConfig, shape: Optional[InputShape] = None,
     window = resolve_window(cfg, shape)
     mseq = max_seq or (shape.seq_len if shape else 2048)
 
+    # named functions, so their jitted programs read ``jit_init``,
+    # ``jit_prefill``, ``jit_decode_step`` in a profiler trace
     if cfg.encoder is not None:
-        return ModelBundle(
-            cfg=cfg, shape=shape, max_seq=mseq, window=window,
-            init=lambda rng: encdec.init_encdec(rng, cfg, max_seq=mseq),
-            loss=lambda p, b: encdec.encdec_loss(p, cfg, b),
-            prefill=lambda p, b: encdec.encdec_prefill(p, cfg, b, max_seq=mseq),
-            decode_step=lambda p, c, t, pos: encdec.encdec_decode_step(p, cfg, c, t, pos),
-        )
+        def init(rng):
+            return encdec.init_encdec(rng, cfg, max_seq=mseq)
 
-    return ModelBundle(
-        cfg=cfg, shape=shape, max_seq=mseq, window=window,
-        init=lambda rng: lm.init_lm(rng, cfg, max_seq=mseq),
-        loss=lambda p, b: lm.lm_loss(p, cfg, b, window=window),
-        prefill=lambda p, b: lm.lm_prefill(p, cfg, b, max_seq=mseq, window=window),
-        decode_step=lambda p, c, t, pos: lm.lm_decode_step(p, cfg, c, t, pos,
-                                                           window=window),
-    )
+        def loss(p, b):
+            return encdec.encdec_loss(p, cfg, b)
+
+        def prefill(p, b):
+            return encdec.encdec_prefill(p, cfg, b, max_seq=mseq)
+
+        def decode_step(p, c, t, pos):
+            return encdec.encdec_decode_step(p, cfg, c, t, pos)
+    else:
+        def init(rng):
+            return lm.init_lm(rng, cfg, max_seq=mseq)
+
+        def loss(p, b):
+            return lm.lm_loss(p, cfg, b, window=window)
+
+        def prefill(p, b):
+            return lm.lm_prefill(p, cfg, b, max_seq=mseq, window=window)
+
+        def decode_step(p, c, t, pos):
+            return lm.lm_decode_step(p, cfg, c, t, pos, window=window)
+
+    return ModelBundle(cfg=cfg, shape=shape, max_seq=mseq, window=window,
+                       init=init, loss=loss, prefill=prefill,
+                       decode_step=decode_step)
 
 
 def build_arch(arch: str, shape: Optional[InputShape] = None, *, smoke: bool = False,

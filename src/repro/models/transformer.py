@@ -101,21 +101,39 @@ def init_stack(rng, cfg) -> List[Any]:
 def _apply_ffn(p, x, cfg):
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in p:
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
+        with jax.named_scope("norm"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
         h = sharding.logical(h, ("batch", "seq", "embed"))
-        x = x + layers.mlp_apply(p["ffn"], h, cfg.act)
+        with jax.named_scope("mlp"):
+            x = x + layers.mlp_apply(p["ffn"], h, cfg.act)
     elif "moe" in p:
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
-        y, aux = moe.moe_ffn(p["moe"], h, cfg)
+        with jax.named_scope("norm"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
+        with jax.named_scope("moe"):
+            y, aux = moe.moe_ffn(p["moe"], h, cfg)
         x = x + y
     return x, aux
+
+
+def _mixer_scope(kind: str) -> str:
+    return {"A": "attention", "M": "ssm"}.get(kind, "xlstm")
 
 
 def _block_full(p, x, cfg, meta, q_pos, window, states):
     """Full-sequence block.  states: prior recurrent state or None.
     Returns (x, aux, cache_material)."""
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    with jax.named_scope("norm"):
+        h = layers.norm_apply(p["norm1"], x, cfg.norm)
     kind = meta["kind"]
+    with jax.named_scope(_mixer_scope(kind)):
+        y, cache = _mixer_full(p, h, cfg, kind, q_pos, window, states)
+    x = x + y
+    x, aux = _apply_ffn(p, x, cfg)
+    x = sharding.logical(x, ("batch", "seq", "embed"))
+    return x, aux, cache
+
+
+def _mixer_full(p, h, cfg, kind, q_pos, window, states):
     if kind == "A":
         # context-parallel fallback (§Perf iter. 3): tokens sharded over the
         # model axis through the attention block when heads don't divide it
@@ -132,16 +150,23 @@ def _block_full(p, x, cfg, meta, q_pos, window, states):
         y, cache = xlstm.mlstm_forward(p["xl"], h, cfg, state=states)
     else:
         y, cache = xlstm.slstm_forward(p["xl"], h, cfg, state=states)
-    x = x + y
-    x, aux = _apply_ffn(p, x, cfg)
-    x = sharding.logical(x, ("batch", "seq", "embed"))
-    return x, aux, cache
+    return y, cache
 
 
 def _block_decode(p, x, cfg, meta, pos, window, cache):
     """One-token block.  x: (B, d).  Returns (x, new_cache)."""
-    h = layers.norm_apply(p["norm1"], x, cfg.norm)
+    with jax.named_scope("norm"):
+        h = layers.norm_apply(p["norm1"], x, cfg.norm)
     kind = meta["kind"]
+    with jax.named_scope(_mixer_scope(kind)):
+        y, cache = _mixer_decode(p, h, cfg, kind, pos, window, cache)
+    x = x + y
+    x3 = x[:, None, :]
+    x3, _ = _apply_ffn(p, x3, cfg)
+    return x3[:, 0, :], cache
+
+
+def _mixer_decode(p, h, cfg, kind, pos, window, cache):
     if kind == "A":
         y, cache = attention.decode_attention(
             p["attn"], h, cache, pos, cfg, window=window,
@@ -152,10 +177,7 @@ def _block_decode(p, x, cfg, meta, pos, window, cache):
         y, cache = xlstm.mlstm_step(p["xl"], h, cache, cfg)
     else:
         y, cache = xlstm.slstm_step(p["xl"], h, cache, cfg)
-    x = x + y
-    x3 = x[:, None, :]
-    x3, _ = _apply_ffn(p, x3, cfg)
-    return x3[:, 0, :], cache
+    return y, cache
 
 
 # --------------------------------------------------------------------------- #

@@ -20,7 +20,9 @@ Mitigation paths implemented for real:
   * fusion: ``fuse_chain`` compiles a chained two-stage pipeline as ONE
     program (one compile) vs two.
 
-All timings are wall-clock measured (perf_counter + block_until_ready).
+All timings are wall-clock measured: the durations of ``repro.spans`` spans
+(perf_counter_ns, ended by block_until_ready where a device result is
+waited for).
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache, spans
 from repro.core.lifecycle import Breakdown, Phase
 from repro.models import registry
 from repro.training import checkpoint
@@ -41,27 +44,6 @@ from repro.training import checkpoint
 
 def _tree_bytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
-
-
-class _Timer:
-    def __init__(self):
-        self.seconds: Dict[Phase, float] = {}
-
-    def phase(self, p: Phase):
-        timer = self
-
-        class _Ctx:
-            def __enter__(self):
-                self.t0 = time.perf_counter()
-
-            def __exit__(self, *a):
-                timer.seconds[p] = timer.seconds.get(p, 0.0) + (
-                    time.perf_counter() - self.t0)
-
-        return _Ctx()
-
-    def breakdown(self) -> Breakdown:
-        return Breakdown(dict(self.seconds))
 
 
 # --------------------------------------------------------------------------- #
@@ -93,8 +75,10 @@ class SnapshotStore:
     def save_params(self, key: str, params) -> int:
         return checkpoint.save(self._path(key), params)
 
-    def load_params(self, key: str):
-        return checkpoint.restore(self._path(key))[0]
+    def read_params(self, key: str) -> checkpoint.HostTree:
+        """The snapshot's leaves in host memory (``checkpoint.place`` puts
+        them on the device)."""
+        return checkpoint.read(self._path(key))[0]
 
     # executables ---------------------------------------------------------- #
     def get_executable(self, key: str):
@@ -111,8 +95,9 @@ class SnapshotStore:
 
 @dataclass
 class ServeStats:
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
+    prefill_s: float = 0.0    # engine.prefill_run
+    decode_s: float = 0.0     # engine.decode
+    run_s: float = 0.0        # engine.run: the whole call
     tokens: int = 0
     logits: Optional[np.ndarray] = None   # last decode step's (B, V) logits
 
@@ -170,53 +155,71 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------ #
     def cold_start(self, *, from_snapshot: bool = False) -> Breakdown:
-        """Full measured startup.  Returns the per-phase breakdown."""
-        t = _Timer()
-        with t.phase(Phase.PROVISION):
-            pass  # process/slice allocation has no CPU-container analogue here
-        with t.phase(Phase.RUNTIME_INIT):
-            self.bundle = registry.build_arch(self.arch, smoke=self.smoke,
-                                              max_seq=self.max_seq)
+        """Full measured startup.  Returns the per-phase breakdown: the
+        durations of the ``engine.start`` phase spans (``repro.spans``).
+        Process or slice allocation has no analogue here, so ``provision``
+        is 0."""
         use_snap = (from_snapshot and self.store is not None
                     and self.store.has_params(self.key))
-        with t.phase(Phase.DEPS_LOAD):
-            if use_snap:
-                self.params = self.store.load_params(self.key)
-            else:
-                # one jitted program on the device, so no float32
-                # temporaries per leaf; an RngBitGenerator key because a
-                # threefry init of full-width granite takes ~22 s to
-                # compile for a v5e, rbg ~6 s
-                self.params = jax.jit(self.bundle.init)(
-                    jax.random.key(self.seed, impl="rbg"))
-            jax.block_until_ready(self.params)
-        with t.phase(Phase.CODE_INIT):
+        with spans.span("engine.start", arch=self.arch,
+                        from_snapshot=use_snap):
+            with spans.span("engine.start.build") as build:
+                self.bundle = registry.build_arch(self.arch, smoke=self.smoke,
+                                                  max_seq=self.max_seq)
+            with spans.span("engine.start.weights") as weights:
+                if use_snap:
+                    with spans.span("engine.start.weights.read"):
+                        host = self.store.read_params(self.key)
+                    with spans.span("engine.start.weights.put"):
+                        self.params = checkpoint.place(host)
+                        jax.block_until_ready(self.params)
+                else:
+                    # one jitted program on the device, so no float32
+                    # temporaries per leaf; an RngBitGenerator key because a
+                    # threefry init of full-width granite takes ~22 s to
+                    # compile for a v5e, rbg ~6 s
+                    with spans.span("engine.start.weights.compile"):
+                        key = jax.random.key(self.seed, impl="rbg")
+                        init = jax.jit(self.bundle.init).lower(key).compile()
+                    with spans.span("engine.start.weights.run"):
+                        self.params = init(key)
+                        jax.block_until_ready(self.params)
             exe = None if self.store is None else \
                 self.store.get_executable(self.key)
             self.last_start = StartPath(from_snapshot=use_snap,
                                         executable_hit=exe is not None)
-            if exe is not None:
-                self._prefill_c, self._decode_c = exe
-            else:
-                params_spec = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params)
-                bspec = self._prefill_batch_spec()
-                self._prefill_c = jax.jit(self.bundle.prefill).lower(
-                    params_spec, bspec).compile()
-                caches_spec = jax.eval_shape(
-                    lambda p, b: self.bundle.prefill(p, b)[1], params_spec, bspec)
-                self._decode_c = jax.jit(self.bundle.decode_step).lower(
-                    params_spec, caches_spec,
-                    jax.ShapeDtypeStruct((self.batch,), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32)).compile()
-                if self.store is not None:
-                    self.store.put_executable(
-                        self.key, (self._prefill_c, self._decode_c))
-        if self.store is not None and not self.store.has_params(self.key):
-            self.store.save_params(self.key, self.params)
+            with spans.span("engine.start.compile",
+                            executable_hit=exe is not None) as code:
+                with compile_cache.counting() as cache:
+                    if exe is not None:
+                        self._prefill_c, self._decode_c = exe
+                    else:
+                        self._compile()
+                code.attrs.update(cache)
+            if self.store is not None and not self.store.has_params(self.key):
+                with spans.span("engine.start.save"):
+                    self.store.save_params(self.key, self.params)
         self.warm = True
-        self.last_breakdown = t.breakdown()
+        self.last_breakdown = Breakdown({
+            Phase.PROVISION: 0.0, Phase.RUNTIME_INIT: build.seconds,
+            Phase.DEPS_LOAD: weights.seconds, Phase.CODE_INIT: code.seconds})
         return self.last_breakdown
+
+    def _compile(self) -> None:
+        params_spec = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params)
+        bspec = self._prefill_batch_spec()
+        self._prefill_c = jax.jit(self.bundle.prefill).lower(
+            params_spec, bspec).compile()
+        caches_spec = jax.eval_shape(
+            lambda p, b: self.bundle.prefill(p, b)[1], params_spec, bspec)
+        self._decode_c = jax.jit(self.bundle.decode_step).lower(
+            params_spec, caches_spec,
+            jax.ShapeDtypeStruct((self.batch,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        if self.store is not None:
+            self.store.put_executable(
+                self.key, (self._prefill_c, self._decode_c))
 
     def shutdown(self):
         """Scale to zero: drop device state (keep nothing warm)."""
@@ -229,30 +232,42 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def serve(self, tokens: np.ndarray, *, decode_steps: int = 8,
               extras: Optional[Dict[str, np.ndarray]] = None) -> Tuple[np.ndarray, ServeStats]:
-        """Greedy generation; measures prefill + decode wall time."""
+        """Greedy generation, one ``engine.run`` span (``repro.spans``):
+        prefill, then per step the previous token fetched to the host and
+        the next step dispatched.  ``ServeStats`` holds span durations."""
         assert self.warm, "cold engine — call cold_start() first"
         stats = ServeStats()
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
-        if extras:
-            batch.update({k: jnp.asarray(v) for k, v in extras.items()})
-        t0 = time.perf_counter()
-        logits, caches, pos = self._prefill_c(self.params, batch)
-        jax.block_until_ready(logits)
-        stats.prefill_s = time.perf_counter() - t0
-        out = []
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t0 = time.perf_counter()
-        p = jnp.asarray(tokens.shape[1], jnp.int32)
-        for i in range(decode_steps):
-            out.append(np.asarray(tok))
-            logits, caches = self._decode_c(self.params, caches, tok, p + i)
+        with spans.span("engine.run", prompt_tokens=int(tokens.shape[1]),
+                        decode_steps=decode_steps) as run:
+            with spans.span("engine.upload"):
+                batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
+                if extras:
+                    batch.update({k: jnp.asarray(v) for k, v in extras.items()})
+            with spans.span("engine.prefill_run") as prefill:
+                logits, caches, pos = self._prefill_c(self.params, batch)
+                jax.block_until_ready(logits)
+            out = []
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(tok)
-        stats.decode_s = time.perf_counter() - t0
+            with spans.span("engine.decode") as decode:
+                p = jnp.asarray(tokens.shape[1], jnp.int32)
+                for i in range(decode_steps):
+                    with spans.span("engine.token_fetch"):
+                        out.append(np.asarray(tok))
+                    with spans.span("engine.step_dispatch"):
+                        logits, caches = self._decode_c(self.params, caches,
+                                                        tok, p + i)
+                        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with spans.span("engine.final_wait"):
+                    jax.block_until_ready(tok)
+            with spans.span("engine.logits_fetch"):
+                stats.logits = np.asarray(logits)
+            generated = np.stack(out, axis=1)
+            self.last_used = time.monotonic()
+        stats.prefill_s = prefill.seconds
+        stats.decode_s = decode.seconds
+        stats.run_s = run.seconds
         stats.tokens = decode_steps
-        stats.logits = np.asarray(logits)
-        self.last_used = time.monotonic()
-        return np.stack(out, axis=1), stats
+        return generated, stats
 
 
 # --------------------------------------------------------------------------- #

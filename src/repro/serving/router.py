@@ -19,6 +19,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.costmodel import CostModel
 from repro.core.lifecycle import Breakdown, FunctionSpec
 from repro.core.metrics import QoSLedger, RequestRecord
@@ -107,40 +108,46 @@ class ServerlessRouter:
     # ------------------------------------------------------------------ #
     def invoke(self, name: str, tokens: Optional[np.ndarray] = None,
                extras=None) -> Tuple[np.ndarray, RequestRecord]:
+        """Serve one request: one ``router.request`` span (``repro.spans``)
+        over ``router.route``, ``pool.start`` on a cold start,
+        ``engine.run`` and ``router.settle``."""
         fdef = self.functions[name]
-        arrival = self._now()
-        self.autoscaler.observe_arrival(name, arrival)
-        self._scale_to_zero(arrival)
-        ctx = self._ctx(arrival)
-        breakdown: Optional[Breakdown] = None
-        cold = False
-        c = self.suite.placement.choose_container(name, ctx)
-        if c is not None:
-            replica = self.pool.replica_for(c)
-            self.autoscaler.on_reuse(c, ctx, arrival - c.warm_since)
-        else:
-            cold = True
-            self.autoscaler.on_miss(name, arrival)
-            fn = self.pool.functions[name]
-            self._reclaim(arrival, fn.memory_mb)
-            replica, breakdown = self.pool.start_replica(
-                name, 0, arrival, from_snapshot=self.use_snapshots)
-        c = replica.container
-        self.state.acquire(c, arrival)
-        if tokens is None:
-            tokens = np.ones((fdef.batch, fdef.max_seq), np.int32)
-        start = self._now()
-        out, _ = self.backend.serve(replica, tokens,
-                                    decode_steps=fdef.decode_steps,
-                                    extras=extras)
-        end = self._now()
-        self.state.release_slot(c, end)
-        self.state.to_idle(c, end)
-        self.state.set_expiry(c, end + self.autoscaler.ttl_for(
-            c, self._ctx(end)))
-        self.state.record_execution(c, [(name, arrival)], start, end,
-                                    cold=cold, bd=breakdown)
-        rec = self.ledger.records[-1]
+        with spans.span("router.request", function=name) as request:
+            with spans.span("router.route"):
+                arrival = self._now()
+                self.autoscaler.observe_arrival(name, arrival)
+                self._scale_to_zero(arrival)
+                ctx = self._ctx(arrival)
+                c = self.suite.placement.choose_container(name, ctx)
+                if c is not None:
+                    replica = self.pool.replica_for(c)
+                    self.autoscaler.on_reuse(c, ctx, arrival - c.warm_since)
+                else:
+                    self.autoscaler.on_miss(name, arrival)
+                    self._reclaim(arrival, self.pool.functions[name].memory_mb)
+            cold = c is None
+            request.attrs["cold"] = cold
+            breakdown: Optional[Breakdown] = None
+            if cold:
+                replica, breakdown = self.pool.start_replica(
+                    name, 0, arrival, from_snapshot=self.use_snapshots)
+            c = replica.container
+            self.state.acquire(c, arrival)
+            if tokens is None:
+                tokens = np.ones((fdef.batch, fdef.max_seq), np.int32)
+            start = self._now()
+            out, _ = self.backend.serve(replica, tokens,
+                                        decode_steps=fdef.decode_steps,
+                                        extras=extras)
+            with spans.span("router.settle"):
+                end = self._now()
+                self.state.release_slot(c, end)
+                self.state.to_idle(c, end)
+                self.state.set_expiry(c, end + self.autoscaler.ttl_for(
+                    c, self._ctx(end)))
+                self.state.record_execution(c, [(name, arrival)], start, end,
+                                            cold=cold, bd=breakdown)
+                rec = self.ledger.records[-1]
         return out, rec
 
     def summary(self) -> Dict[str, float]:

@@ -132,11 +132,10 @@ def make_rules(cfg, shape, mesh: Mesh, *, seq_shard: Optional[bool] = None) -> D
     # Iteration 1b (measured): EXCLUDE MoE archs — the dispatch einsum
     # touches every local expert's weights each step, so replication turns
     # into 16x more per-step HBM weight reads (jamba decode bound
-    # 0.035s -> 0.058s, qwen3 0.027s -> 0.063s).  `full_param_count`
-    # keeps the guard consistent when roofline scales layer counts.
+    # 0.035s -> 0.058s, qwen3 0.027s -> 0.063s).
     if shape.kind == "decode" and msize and cfg.moe is None:
         itemsize = 2 if cfg.param_dtype == "bfloat16" else 4
-        n_params = getattr(cfg, "full_param_count", 0) or cfg.param_count()
+        n_params = cfg.param_count()
         per_chip_gb = n_params * itemsize / msize / 2**30
         if per_chip_gb <= 8.0:
             rules["fsdp"] = None

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -84,26 +84,45 @@ def _insert(tree: Any, entries: List[PathEntry], leaf) -> Any:
     return tree
 
 
-def restore(path: str) -> Tuple[Any, dict]:
-    """Read a checkpoint written by :func:`save`; returns
-    ``(params, extra)`` with the leaves put on the default device in one
-    transfer."""
+class HostTree(NamedTuple):
+    """A checkpoint's leaves in host memory, with their pytree paths."""
+
+    paths: List[List[PathEntry]]
+    leaves: List[np.ndarray]
+
+
+def read(path: str) -> Tuple[HostTree, dict]:
+    """Read a checkpoint written by :func:`save` into host memory; returns
+    ``(HostTree, extra)``."""
     with np.load(path, allow_pickle=False) as z:
         manifest = json.loads(z[_MANIFEST].tobytes().decode())
         if manifest.get("format") != FORMAT:
             raise ValueError(f"{path}: checkpoint format "
                              f"{manifest.get('format')!r} != {FORMAT}")
-        specs = manifest["leaves"]
-        leaves = []
-        for spec in specs:
+        paths, leaves = [], []
+        for spec in manifest["leaves"]:
             a = z["/".join(map(str, spec["path"]))]
             want = jnp.dtype(spec["dtype"])
+            paths.append(spec["path"])
             leaves.append(a if a.dtype == want else a.view(want))
-    leaves = jax.device_put(leaves)
+    return HostTree(paths, leaves), manifest["extra"]
+
+
+def place(host: HostTree) -> Any:
+    """The params pytree, its leaves put on the default device in one
+    transfer."""
     tree = None
-    for spec, leaf in zip(specs, leaves):
-        tree = _insert(tree, spec["path"], leaf)
-    return tree, manifest["extra"]
+    for path, leaf in zip(host.paths, jax.device_put(host.leaves)):
+        tree = _insert(tree, path, leaf)
+    return tree
+
+
+def restore(path: str) -> Tuple[Any, dict]:
+    """Read a checkpoint written by :func:`save`; returns
+    ``(params, extra)`` with the leaves put on the default device in one
+    transfer."""
+    host, extra = read(path)
+    return place(host), extra
 
 
 def tree_equal(a: Any, b: Any) -> bool:

@@ -62,6 +62,7 @@ def test_snapshot_store_roundtrips_bf16_params(tmp_path):
 
     from repro.configs import granite3_2b
     from repro.models import registry
+    from repro.training import checkpoint
     from repro.training.checkpoint import tree_equal
 
     cfg = dataclasses.replace(granite3_2b.SMOKE, param_dtype="bfloat16",
@@ -69,7 +70,7 @@ def test_snapshot_store_roundtrips_bf16_params(tmp_path):
     params = registry.build(cfg, max_seq=16).init(jax.random.key(0))
     st = SnapshotStore(str(tmp_path))
     st.save_params("bf16", params)
-    back = st.load_params("bf16")
+    back = checkpoint.place(st.read_params("bf16"))
     assert tree_equal(params, back)
     assert {x.dtype for x in jax.tree.leaves(back)} == {jnp.dtype(jnp.bfloat16)}
 

@@ -86,7 +86,7 @@ def test_train_checkpoint_serve_loop(tmp_path):
     # checkpoint doubles as the snapshot image format
     trained, _ = checkpoint.restore(ck)
     store.save_params("trained_model", trained)
-    loaded = store.load_params("trained_model")
+    loaded = checkpoint.place(store.read_params("trained_model"))
     assert checkpoint.tree_equal(trained, loaded)
     out, stats = e.serve(np.ones((1, 32), np.int32), decode_steps=4)
     assert out.shape == (1, 4)
