@@ -12,6 +12,10 @@ Each op has three execution paths:
   Pallas interpreter only on the CPU backend (tests).
 * ``impl="oracle"`` — the naive oracles in ``ref.py`` (tests only).
 
+Models ask for ``"auto"`` (the config default): ``choose_flash_impl`` picks
+the path of each full-sequence attention call from what it can see, and
+every other op reads ``"auto"`` as ``"reference"``.
+
 All paths agree to numerical tolerance; see ``tests/test_kernels.py``.
 """
 from __future__ import annotations
@@ -25,6 +29,7 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 
 NEG_INF = -1e30
+REF_CHUNK = 1024        # the reference's q and kv chunk (``_flash_reference``)
 
 
 # --------------------------------------------------------------------------- #
@@ -32,7 +37,7 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------------- #
 
 
-def _pick_chunk(s: int, target: int) -> int:
+def pick_chunk(s: int, target: int) -> int:
     """Largest divisor of s that is <= target (halving would degrade to
     chunk=4 for whisper's 1500-frame encoder: 375x375 blocks)."""
     c = min(target, s)
@@ -41,14 +46,30 @@ def _pick_chunk(s: int, target: int) -> int:
     return max(c, 1)
 
 
+def choose_flash_impl(requested: str, *, train: bool, self_attn: bool,
+                      sq: int, skv: int) -> str:
+    """The flash-attention path for one call.  ``"auto"`` is the Pallas
+    kernel for forward-only (it has no VJP) self-attention on a TPU whose
+    lengths are multiples of its smallest block, and the reference
+    everywhere else: the CPU, training, cross-attention, whisper's
+    1500-frame encoder.  Any other ``requested`` path is kept."""
+    if requested != "auto":
+        return requested
+    from repro.kernels.flash_attention import MIN_BLOCK
+    if (jax.default_backend() == "tpu" and not train and self_attn
+            and sq % MIN_BLOCK == 0 and skv % MIN_BLOCK == 0):
+        return "pallas"
+    return "reference"
+
+
 def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos,
-                     q_chunk=1024, kv_chunk=1024):
+                     q_chunk=REF_CHUNK, kv_chunk=REF_CHUNK):
     """Chunked online-softmax attention in pure jnp (fp32 accumulators)."""
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
-    qc = _pick_chunk(sq, q_chunk)
-    kc = _pick_chunk(skv, kv_chunk)
+    qc = pick_chunk(sq, q_chunk)
+    kc = pick_chunk(skv, kv_chunk)
     scale = 1.0 / (d ** 0.5)
 
     # (B, Skv, Hkv, D) -> (nk, B, kc, Hkv, D)
@@ -154,7 +175,7 @@ def ssm_scan(u, delta, A, B, C, D, h0, *, chunk: int = 256,
         return ssm_scan_pallas(u, delta, A, B, C, D, h0)
     bsz, t, din = u.shape
     n = A.shape[1]
-    c = _pick_chunk(t, chunk)
+    c = pick_chunk(t, chunk)
 
     uf = u.astype(jnp.float32)
     df = delta.astype(jnp.float32)

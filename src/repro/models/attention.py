@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import flash_attention, ops
 from repro.models import layers
 
 
@@ -56,11 +56,13 @@ def _proj_qkv(p, x, kv_x, h, hkv, hd):
 
 def full_attention(p, x, cfg, *, q_pos, causal=True, window=None,
                    kv_x=None, use_rope=True, impl=None,
-                   num_heads=None, num_kv_heads=None, return_kv=False):
+                   num_heads=None, num_kv_heads=None, return_kv=False,
+                   train=False):
     """Full-sequence attention (train / prefill / encoder / cross).
 
     x: (B, Sq, d); kv_x: (B, Skv, d) for cross-attention (default: x).
     q_pos: (Sq,) absolute positions of the queries (= kv positions when self).
+    ``train`` says a gradient will be taken (``ops.choose_flash_impl``).
     """
     h = num_heads or cfg.num_heads
     hkv = num_kv_heads or cfg.num_kv_heads
@@ -73,14 +75,33 @@ def full_attention(p, x, cfg, *, q_pos, causal=True, window=None,
         cos, sin = layers.rope_cos_sin(q_pos, hd, cfg.rope_theta)
         q = layers.apply_rope(q, cos[None], sin[None])
         k = layers.apply_rope(k, cos[None], sin[None])
+    impl = ops.choose_flash_impl(impl or cfg.attention_impl, train=train,
+                                 self_attn=self_attn, sq=q.shape[1],
+                                 skv=k.shape[1])
     out = ops.flash_attention(
         q, k, v, causal=causal and self_attn, window=window,
-        q_pos=q_pos, kv_pos=kv_pos, impl=impl or cfg.attention_impl)
+        q_pos=q_pos, kv_pos=kv_pos, impl=impl)
     b, sq = x.shape[0], x.shape[1]
     y = out.reshape(b, sq, h * hd) @ p["wo"]
     if return_kv:
         return y, (k, v)
     return y
+
+
+def prefill_attention(cfg, seq: int, window: Optional[int]) -> dict:
+    """The path a prefill of ``seq`` tokens takes for causal
+    self-attention, and the score blocks it computes of all it could, per
+    head group: the kernel's block pairs, or the reference's chunk pairs
+    (it computes all).  Span attributes of ``engine.start.compile``."""
+    impl = ops.choose_flash_impl(cfg.attention_impl, train=False,
+                                 self_attn=True, sq=seq, skv=seq)
+    if impl == "pallas":
+        done, total = flash_attention.block_counts(
+            seq, seq, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.dtype, causal=True, window=window)
+    else:
+        done = total = (seq // ops.pick_chunk(seq, ops.REF_CHUNK)) ** 2
+    return {"prefill_attention": impl, "attention_blocks": f"{done}/{total}"}
 
 
 def init_cache(cfg, batch: int, max_seq: int, *, window: Optional[int] = None,

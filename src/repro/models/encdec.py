@@ -103,7 +103,8 @@ def encode(p, cfg, frames, *, train=False):
         x = x + attention.full_attention(lp["attn"], h, cfg, q_pos=pos,
                                          causal=False, use_rope=False,
                                          num_heads=e.num_heads,
-                                         num_kv_heads=e.num_heads)
+                                         num_kv_heads=e.num_heads,
+                                         train=train)
         h = layers.norm_apply(lp["norm2"], x, cfg.norm)
         x = x + layers.mlp_apply(lp["ffn"], h, cfg.act)
         return x, None
@@ -133,13 +134,15 @@ def _dec_full(p, cfg, tokens, enc_out, *, train=False):
         # don't divide the model axis
         h = sharding.logical(h, ("batch", "attn_seq", None))
         y, kv = attention.full_attention(lp["self"], h, cfg, q_pos=q_pos,
-                                         use_rope=False, return_kv=True)
+                                         use_rope=False, return_kv=True,
+                                         train=train)
         y = sharding.logical(y, ("batch", "attn_seq", None))
         x = x + y
         h = layers.norm_apply(lp["norm_x"], x, cfg.norm)
         y, xkv = attention.full_attention(lp["cross"], h, cfg, q_pos=q_pos,
                                           kv_x=enc_out, causal=False,
-                                          use_rope=False, return_kv=True)
+                                          use_rope=False, return_kv=True,
+                                          train=train)
         x = x + y
         h = layers.norm_apply(lp["norm2"], x, cfg.norm)
         x = x + layers.mlp_apply(lp["ffn"], h, cfg.act)

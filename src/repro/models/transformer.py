@@ -119,28 +119,28 @@ def _mixer_scope(kind: str) -> str:
     return {"A": "attention", "M": "ssm"}.get(kind, "xlstm")
 
 
-def _block_full(p, x, cfg, meta, q_pos, window, states):
+def _block_full(p, x, cfg, meta, q_pos, window, states, train):
     """Full-sequence block.  states: prior recurrent state or None.
     Returns (x, aux, cache_material)."""
     with jax.named_scope("norm"):
         h = layers.norm_apply(p["norm1"], x, cfg.norm)
     kind = meta["kind"]
     with jax.named_scope(_mixer_scope(kind)):
-        y, cache = _mixer_full(p, h, cfg, kind, q_pos, window, states)
+        y, cache = _mixer_full(p, h, cfg, kind, q_pos, window, states, train)
     x = x + y
     x, aux = _apply_ffn(p, x, cfg)
     x = sharding.logical(x, ("batch", "seq", "embed"))
     return x, aux, cache
 
 
-def _mixer_full(p, h, cfg, kind, q_pos, window, states):
+def _mixer_full(p, h, cfg, kind, q_pos, window, states, train):
     if kind == "A":
         # context-parallel fallback (§Perf iter. 3): tokens sharded over the
         # model axis through the attention block when heads don't divide it
         h = sharding.logical(h, ("batch", "attn_seq", None))
         y, kv = attention.full_attention(
             p["attn"], h, cfg, q_pos=q_pos, window=window,
-            use_rope=cfg.encoder is None, return_kv=True)
+            use_rope=cfg.encoder is None, return_kv=True, train=train)
         y = sharding.logical(y, ("batch", "attn_seq", None))
         cache = {"k": kv[0], "v": kv[1]}
     elif kind == "M":
@@ -197,7 +197,7 @@ def stack_full(stack_params, x, cfg, *, q_pos, window=None, train=False):
         caches = []
         for pos, meta in enumerate(metas):
             x, a, c = _block_full(period_params[pos], x, cfg, meta, q_pos,
-                                  window, None)
+                                  window, None, train)
             aux = aux + a
             caches.append(c)
         return (x, aux), tuple(caches)

@@ -38,7 +38,7 @@ import numpy as np
 
 from repro import compile_cache, spans
 from repro.core.lifecycle import Breakdown, Phase
-from repro.models import registry
+from repro.models import attention, registry
 from repro.training import checkpoint
 
 
@@ -188,8 +188,12 @@ class InferenceEngine:
                 self.store.get_executable(self.key)
             self.last_start = StartPath(from_snapshot=use_snap,
                                         executable_hit=exe is not None)
+            cfg = self.bundle.cfg
+            attn = (attention.prefill_attention(cfg, self.max_seq,
+                                                self.bundle.window)
+                    if "A" in cfg.layer_pattern else {})
             with spans.span("engine.start.compile",
-                            executable_hit=exe is not None) as code:
+                            executable_hit=exe is not None, **attn) as code:
                 with compile_cache.counting() as cache:
                     if exe is not None:
                         self._prefill_c, self._decode_c = exe

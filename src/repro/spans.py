@@ -317,9 +317,10 @@ def device_clock(spans: List[Tuple[int, int, str]], links: List[Link]
 
 
 def read_trace(xplane_path: str) -> dict:
-    """The offset, and the device's idle time inside ``engine.run`` by
-    program span, with the host spans put on the device's clock run by
-    run (``device_clock``)."""
+    """The attributes of each ``engine.start.compile`` in the trace (the
+    prefill attention path and its blocks among them), the offset, and
+    the device's idle time inside ``engine.run`` by program span, with the
+    host spans put on the device's clock run by run (``device_clock``)."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(xplane_path)
@@ -338,6 +339,10 @@ def read_trace(xplane_path: str) -> dict:
     out = {
         "offset_ms": lead_ns(links) / 1e6, "linked": len(links),
         "modules": modules, "engine_runs": len(runs),
+        "compiles": [dict(ev.stats) for plane in pd.planes
+                     if plane.name.startswith("/host:")
+                     for line in plane.lines for ev in line.events
+                     if ev.name == "engine.start.compile"],
     }
     if offsets:
         offsets.sort()

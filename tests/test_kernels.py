@@ -8,8 +8,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.models import registry
 from repro.kernels.decode_attention import decode_attention_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import block_counts, flash_attention_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
 RNG = np.random.default_rng(42)
@@ -28,6 +29,8 @@ ATTN_CASES = [
     (1, 128, 384, 2, 2, 128, True, None),   # suffix-aligned prefill
     (1, 128, 128, 4, 4, 64, False, None),   # encoder (non-causal)
     (3, 256, 256, 6, 2, 48, True, 128),
+    (1, 256, 256, 8, 2, 80, True, None),    # danube's G=4 and head_dim 80
+    (1, 512, 512, 8, 2, 80, True, 256),     # the same, windowed
 ]
 
 
@@ -131,3 +134,95 @@ def test_flash_attention_pallas_vs_reference_chunked_grid():
                                      block_q=bq, block_k=bk)
         np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5,
                                    err_msg=f"blocks {bq}x{bk}")
+
+
+SKIP_CASES = [
+    # (seq, window, poisoned keys, rows that never see them, computed/total)
+    (1024, None, (512, 1024), (0, 512), (3, 4)),       # causal: 512x512
+    (1536, 128, (0, 512), (1024, 1536), (5, 9)),       # and the window
+]
+
+
+@pytest.mark.parametrize("case", SKIP_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_skips_masked_blocks(case, dtype):
+    """Blocks that the mask hides whole are neither computed nor read:
+    NaN values in them leave the rows that cannot see them exact (a
+    computed, masked block would give 0 * NaN), at danube's head ratio."""
+    s, window, (k0, k1), (r0, r1), counts = case
+    assert block_counts(s, s, 8, 2, 80, dtype, causal=True,
+                        window=window) == counts
+    q = jnp.asarray(RNG.normal(size=(1, s, 8, 80)), dtype)
+    k = jnp.asarray(RNG.normal(size=(1, s, 2, 80)), dtype)
+    v = jnp.asarray(RNG.normal(size=(1, s, 2, 80)), dtype)
+    want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
+    got = flash_attention_pallas(q, k, v.at[:, k0:k1].set(jnp.nan),
+                                 causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(got[:, r0:r1], np.float32),
+                               np.asarray(want[:, r0:r1], np.float32),
+                               **_tol(dtype))
+
+
+CHOICES = [
+    # (backend, train, self_attn, seq, impl)
+    ("tpu", False, True, 4096, "pallas"),
+    ("cpu", False, True, 4096, "reference"),
+    ("tpu", True, True, 4096, "reference"),       # no VJP
+    ("tpu", False, False, 4096, "reference"),      # cross-attention
+    ("tpu", False, True, 1500, "reference"),       # whisper's encoder
+]
+
+
+@pytest.mark.parametrize("case", CHOICES)
+def test_choose_flash_impl(monkeypatch, case):
+    backend, train, self_attn, seq, want = case
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.choose_flash_impl("auto", train=train, self_attn=self_attn,
+                                 sq=seq, skv=seq) == want
+    for explicit in ("reference", "pallas", "oracle"):
+        assert ops.choose_flash_impl(explicit, train=train,
+                                     self_attn=self_attn, sq=seq,
+                                     skv=seq) == explicit
+
+
+def test_prefill_attention_span_attributes(monkeypatch):
+    from repro.config import ModelConfig
+    from repro.models import attention
+
+    # h2o-danube-1.8b's attention (chipbench/configs/h2o-danube-1.8b.json)
+    cfg = ModelConfig(name="danube", family="dense", source="-",
+                      num_heads=32, num_kv_heads=8, head_dim=80)
+    assert attention.prefill_attention(cfg, 4096, 4096) == {
+        "prefill_attention": "reference", "attention_blocks": "16/16"}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention.prefill_attention(cfg, 4096, 4096) == {
+        "prefill_attention": "pallas", "attention_blocks": "36/64"}
+
+
+def _pallas_calls(fn, *args):
+    # a fresh closure, so that no earlier trace of ``fn`` is reused
+    return str(jax.make_jaxpr(lambda *a: fn(*a))(*args)).count("pallas_call")
+
+
+def test_auto_attention_path_by_platform_and_gradient(monkeypatch):
+    """``"auto"``: the CPU keeps the reference everywhere; on a TPU only
+    prefill takes the kernel, one call per layer scan, and the loss's
+    gradient still runs (through the reference)."""
+    from repro.models import lm
+
+    bundle = registry.build_arch("granite-3-2b", smoke=True, max_seq=128)
+    cfg = bundle.cfg
+    assert cfg.attention_impl == "auto"
+    params = bundle.init(jax.random.key(0))
+    tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size, (1, 128)), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+
+    def loss(p):
+        return lm.lm_loss(p, cfg, batch)[0]
+
+    assert _pallas_calls(bundle.prefill, params, batch) == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _pallas_calls(bundle.prefill, params, batch) == 1
+    assert _pallas_calls(jax.grad(loss), params) == 0
+    grads = jax.grad(loss)(params)
+    assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))

@@ -115,6 +115,17 @@ def _flash():
              ((1, 512, 8, 64), bf)])
 
 
+def _flash_danube():
+    from repro.kernels.flash_attention import flash_attention_pallas
+
+    bf = jnp.bfloat16     # h2o-danube-1.8b's prefill: 32 q heads, 8 kv, d 80
+    return (lambda q, k, v: flash_attention_pallas(q, k, v, causal=True,
+                                                   window=4096,
+                                                   interpret=False),
+            [((1, 4096, 32, 80), bf), ((1, 4096, 8, 80), bf),
+             ((1, 4096, 8, 80), bf)])
+
+
 def _decode():
     from repro.kernels.decode_attention import decode_attention_pallas
 
@@ -146,9 +157,10 @@ def _cluster():
             [((c, *shapes[k]), jnp.float32) for k in order])
 
 
-@pytest.mark.parametrize("kernel", [_flash, _decode, _ssm, _cluster],
-                         ids=["flash_attention", "decode_attention",
-                              "ssm_scan", "cluster_step"])
+@pytest.mark.parametrize("kernel", [_flash, _flash_danube, _decode, _ssm,
+                                    _cluster],
+                         ids=["flash_attention", "flash_attention_danube",
+                              "decode_attention", "ssm_scan", "cluster_step"])
 def test_pallas_kernel_compiles_natively(one_chip, kernel):
     fn, arg_shapes = kernel()
     args = [_spec(one_chip, shape, dtype) for shape, dtype in arg_shapes]
