@@ -24,16 +24,45 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-Experts settings (Switch-style capacity dispatch)."""
+    """Mixture-of-Experts settings (dropless sorted dispatch, ``models/moe.py``).
 
-    num_experts: int
+    ``experts_held`` gives this device's share of an expert-parallel layer,
+    experts 0 .. experts_held - 1 (chip 0's): the router keeps all
+    ``num_experts`` outputs and its ``top_k``, and the layer computes only
+    the held experts' part."""
+
+    num_experts: int                # the router's width: experts of the layer
     top_k: int
     expert_ff: int                  # per-expert FFN hidden dim
     every_n_layers: int = 1         # MoE layer every n layers (Jamba: 2)
-    dense_residual: bool = False    # Arctic: dense FFN branch parallel to experts
-    dense_residual_ff: int = 0      # hidden dim of the dense residual branch
-    capacity_factor: float = 1.25
+    first_dense: int = 0            # leading layers with a dense FFN (DeepSeek: 1)
+    shared_ff: int = 0              # hidden dim of a dense FFN every token
+                                    # passes beside the experts (Arctic's dense
+                                    # residual, DeepSeek's shared experts); 0: none
+    norm_topk: bool = True          # renormalise the top-k weights to sum 1
+    experts_held: int = 0           # routed experts held here; 0: all
     router_aux_weight: float = 0.01
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), under DeepSeek-V2's keys."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+    def __post_init__(self):
+        if self.type != "yarn":
+            raise ValueError(f"rope_scaling type {self.type!r} is not built")
 
 
 @dataclass(frozen=True)
@@ -91,6 +120,13 @@ class ModelConfig:
     # attention flavour ------------------------------------------------------ #
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None   # a dict is read as YarnConfig
+    # multi-head latent attention (DeepSeek-V2, ``models/mla.py``) when
+    # kv_lora_rank > 0: keys and values through a latent of that width
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0       # per-head query/key dims not rotated
+    qk_rope_head_dim: int = 0       # rotated dims; the rotated key is shared
+    v_head_dim: int = 0
     sliding_window: Optional[int] = None     # SWA width (h2o-danube; jamba@500k)
     # block pattern ----------------------------------------------------------- #
     # 'A' attention+FFN, 'M' mamba, 'S' sLSTM, 'L' mLSTM. Tiled over num_layers.
@@ -119,6 +155,9 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.num_kv_heads == 0:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               YarnConfig(**self.rope_scaling))
 
     # derived ----------------------------------------------------------- #
     @property
@@ -136,6 +175,17 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Query/key width per head (MLA: rotated and not; else head_dim)."""
+        if self.mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
+
     def moe_layer_mask(self) -> Tuple[bool, ...]:
         """Which layers carry a routed-MoE FFN.
 
@@ -146,8 +196,9 @@ class ModelConfig:
         """
         if self.moe is None:
             return tuple(False for _ in range(self.num_layers))
-        n = self.moe.every_n_layers
-        return tuple(i % n == n - 1 for i in range(self.num_layers))
+        n, lead = self.moe.every_n_layers, self.moe.first_dense
+        return tuple(i >= lead and (i - lead) % n == n - 1
+                     for i in range(self.num_layers))
 
     # parameter counting (for roofline MODEL_FLOPS = 6·N·D) -------------- #
     def param_count(self, active_only: bool = False) -> int:
@@ -164,17 +215,22 @@ class ModelConfig:
             # FFN half (shared by every mixer kind except xLSTM's d_ff == 0)
             if moe_mask[i]:
                 m = self.moe
-                k = m.top_k if active_only else m.num_experts
+                k = m.top_k if active_only else m.held
                 n += k * ff_mults * d * m.expert_ff
                 n += d * m.num_experts  # router
-                if m.dense_residual:
-                    n += ff_mults * d * (m.dense_residual_ff or self.d_ff)
+                n += ff_mults * d * m.shared_ff
             elif self.d_ff:
                 n += ff_mults * d * self.d_ff
             if self.d_ff or moe_mask[i]:
                 n += d  # FFN pre-norm
             # mixer half
-            if kind == "A":
+            if kind == "A" and self.mla:
+                r, h = self.kv_lora_rank, self.num_heads
+                n += d * h * self.qk_head_dim              # wq
+                n += d * (r + self.qk_rope_head_dim) + r   # kv_a, its norm
+                n += r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                n += h * self.v_head_dim * d + d           # wo, norm
+            elif kind == "A":
                 n += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
                 if self.qkv_bias:
                     n += self.q_dim + 2 * self.kv_dim
@@ -238,6 +294,7 @@ ARCH_IDS = (
     "xlstm_125m",
     "arctic_480b",
     "granite3_2b",
+    "deepseek_v2_lite",
 )
 
 # external ids ("--arch starcoder2-15b") -> module names
@@ -253,6 +310,7 @@ _ALIAS.update({
     "xlstm-125m": "xlstm_125m",
     "arctic-480b": "arctic_480b",
     "granite-3-2b": "granite3_2b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 })
 
 
@@ -317,18 +375,21 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 256,
         remat=False,
     )
     if cfg.moe is not None:
-        # capacity_factor = E/k -> capacity == group size -> nothing drops.
-        # Dropping couples tokens non-causally (a future token can evict an
-        # earlier one), which would break the decode == full-forward
-        # invariant the smoke tests assert.
+        m = cfg.moe
+        share = 4 / m.num_experts       # the held share, kept
         kw["moe"] = replace(
-            cfg.moe,
+            m,
             num_experts=4,
-            top_k=min(cfg.moe.top_k, 2),
+            top_k=min(m.top_k, 2),
             expert_ff=d_model * 2,
-            capacity_factor=4.0 / min(cfg.moe.top_k, 2) * 2,
-            dense_residual_ff=d_model * 2 if cfg.moe.dense_residual else 0,
+            shared_ff=d_model * 2 if m.shared_ff else 0,
+            first_dense=min(m.first_dense, layers - 1),
+            experts_held=max(1, round(m.experts_held * share)) if m.experts_held else 0,
         )
+    if cfg.mla:
+        kw.update(kv_lora_rank=d_model // 4, qk_nope_head_dim=head_dim,
+                  qk_rope_head_dim=head_dim // 2, v_head_dim=head_dim,
+                  num_kv_heads=heads)
     if cfg.ssm is not None:
         kw["ssm"] = replace(cfg.ssm, d_state=8)
     if cfg.xlstm is not None:

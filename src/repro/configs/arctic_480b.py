@@ -16,6 +16,6 @@ CONFIG = ModelConfig(
     d_ff=4864,
     vocab_size=32000,
     moe=MoEConfig(num_experts=128, top_k=2, expert_ff=4864, every_n_layers=1,
-                  dense_residual=True, dense_residual_ff=4864),
+                  shared_ff=4864),
 )
 SMOKE = reduced(CONFIG)

@@ -194,6 +194,7 @@ class EngineBackend(ExecutionBackend):
         prof = self.profile(replica.function)
         engine = InferenceEngine(prof.arch, smoke=prof.smoke,
                                  max_seq=prof.max_seq, batch=prof.batch,
+                                 decode_steps=prof.decode_steps,
                                  store=self.store)
         replica.engine = engine
         return engine.cold_start(from_snapshot=from_snapshot)

@@ -56,26 +56,28 @@ VMEM_BUDGET = 48 * 2 ** 20      # what the estimate may use; the rest is slack
 DEAD, PARTIAL, FULL = 0, 1, 2   # block kinds in the prefetched table
 
 
-def _vmem_bytes(g: int, bq: int, bk: int, d: int, itemsize: int) -> int:
+def _vmem_bytes(g: int, bq: int, bk: int, d: int, itemsize: int,
+                dv: int) -> int:
     """Estimated VMEM working set of one grid step (lanes padded to 128)."""
-    lanes = -(-d // 128) * 128
+    lanes, vlanes = (-(-n // 128) * 128 for n in (d, dv))
     rows = g * bq
-    tiles = 2 * itemsize * lanes * (2 * rows + 2 * bk)   # q, out, k, v x2
+    tiles = 2 * itemsize * ((rows + bk) * lanes + (rows + bk) * vlanes)
     pos = 2 * 4 * 128 * (bq + 8 * bk // 128)             # (bq,1), (1,bk) x2
-    state = 4 * rows * (2 * 128 + lanes)                  # m, l, acc
+    state = 4 * rows * (2 * 128 + vlanes)                 # m, l, acc
     scores = rows * bk * (4 + 4 + itemsize)               # s, p, p cast
     return tiles + pos + state + scores
 
 
-def choose_blocks(sq: int, skv: int, g: int, d: int,
-                  itemsize: int) -> Tuple[int, int]:
+def choose_blocks(sq: int, skv: int, g: int, d: int, itemsize: int,
+                  dv: Optional[int] = None) -> Tuple[int, int]:
     """(block_q, block_k): the largest sizes that divide the sequences and
-    fit ``VMEM_BUDGET``; a sequence no size divides is one block."""
+    fit ``VMEM_BUDGET``; a sequence no size divides is one block.  ``dv``
+    is the value head dim where it is not ``d``."""
     qs = [b for b in BLOCK_SIZES if sq % b == 0] or [sq]
     ks = [b for b in BLOCK_SIZES if skv % b == 0] or [skv]
     for bq in qs:
         for bk in ks:
-            if _vmem_bytes(g, bq, bk, d, itemsize) <= VMEM_BUDGET:
+            if _vmem_bytes(g, bq, bk, d, itemsize, dv or d) <= VMEM_BUDGET:
                 return bq, bk
     return qs[-1], ks[-1]
 
@@ -105,10 +107,12 @@ def block_table(q_pos, kv_pos, bq: int, bk: int, *, causal: bool,
 
 
 def block_counts(sq: int, skv: int, hq: int, hkv: int, d: int, dtype, *,
-                 causal: bool, window: Optional[int]) -> Tuple[int, int]:
+                 causal: bool, window: Optional[int],
+                 dv: Optional[int] = None) -> Tuple[int, int]:
     """(computed, total) block pairs per head group of the kernel at
     these shapes, with the default (suffix-aligned) positions."""
-    bq, bk = choose_blocks(sq, skv, hq // hkv, d, jnp.dtype(dtype).itemsize)
+    bq, bk = choose_blocks(sq, skv, hq // hkv, d, jnp.dtype(dtype).itemsize,
+                           dv)
     _, _, kind = block_table(np.arange(sq) + (skv - sq), np.arange(skv),
                              bq, bk, causal=causal, window=window, xp=np)
     return int((kind != DEAD).sum()), int(kind.size)
@@ -157,18 +161,21 @@ def _attn_kernel(first_ref, last_ref, kind_ref, qpos_ref, kpos_ref,
     @pl.when(ki == nk - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = out.reshape(g, bq, d).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(g, bq, -1).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "block_q", "block_k",
+                                             "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            q_pos=None, kv_pos=None,
+                           scale: Optional[float] = None,
                            block_q: Optional[int] = None,
                            block_k: Optional[int] = None,
                            interpret: Optional[bool] = None):
-    """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
+    """q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv) ->
+    (B, Sq, Hq, Dv).  Scores are scaled by ``scale`` (default D**-0.5).
 
     Blocks come from ``choose_blocks`` unless given (tests sweep them).
     ``interpret=None`` runs natively on an accelerator and through the
@@ -176,9 +183,9 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     if interpret is None:
         interpret = interpret_on_this_platform()
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     g = hq // hkv
-    cq, ck = choose_blocks(sq, skv, g, d, q.dtype.itemsize)
+    cq, ck = choose_blocks(sq, skv, g, d, q.dtype.itemsize, dv)
     bq, bk = block_q or cq, block_k or ck
     if sq % bq or skv % bk:
         raise ValueError(f"blocks {bq}x{bk} do not divide {sq}x{skv}")
@@ -205,7 +212,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         return bi, j, qi, 0
 
     kernel = functools.partial(_attn_kernel, causal=causal, window=window,
-                               scale=1.0 / (d ** 0.5))
+                               scale=scale or 1.0 / (d ** 0.5))
     # heads-major layout: every tile is a (block, head_dim) slab, and the
     # G q-heads of kv-head j are rows j*G .. j*G+G-1
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
@@ -221,15 +228,15 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                 pl.BlockSpec((1, 1, bk), kpos_map),
                 pl.BlockSpec((1, g, bq, d), q_map),
                 pl.BlockSpec((1, 1, bk, d), kv_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
+                pl.BlockSpec((1, 1, bk, dv), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, g, bq, d), q_map),
+            out_specs=pl.BlockSpec((1, g, bq, dv), q_map),
             scratch_shapes=[
                 pltpu.VMEM((g * bq, 1), jnp.float32),   # m (running max)
                 pltpu.VMEM((g * bq, 1), jnp.float32),   # l (running denom)
-                pltpu.VMEM((g * bq, d), jnp.float32),   # acc
+                pltpu.VMEM((g * bq, dv), jnp.float32),  # acc
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),

@@ -62,19 +62,19 @@ def choose_flash_impl(requested: str, *, train: bool, self_attn: bool,
     return "reference"
 
 
-def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos,
+def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos, scale=None,
                      q_chunk=REF_CHUNK, kv_chunk=REF_CHUNK):
     """Chunked online-softmax attention in pure jnp (fp32 accumulators)."""
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     g = hq // hkv
     qc = pick_chunk(sq, q_chunk)
     kc = pick_chunk(skv, kv_chunk)
-    scale = 1.0 / (d ** 0.5)
+    scale = scale or 1.0 / (d ** 0.5)
 
     # (B, Skv, Hkv, D) -> (nk, B, kc, Hkv, D)
     kb = jnp.moveaxis(k.reshape(b, skv // kc, kc, hkv, d), 1, 0)
-    vb = jnp.moveaxis(v.reshape(b, skv // kc, kc, hkv, d), 1, 0)
+    vb = jnp.moveaxis(v.reshape(b, skv // kc, kc, hkv, dv), 1, 0)
     kpb = kv_pos.reshape(skv // kc, kc)
 
     def q_block(args):
@@ -99,7 +99,7 @@ def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos,
                 "bhgqk,bkhd->bhgqd", p, vj.astype(jnp.float32))
             return (acc, m_new, l), None
 
-        acc0 = jnp.zeros((b, hkv, g, qc, d), jnp.float32)
+        acc0 = jnp.zeros((b, hkv, g, qc, dv), jnp.float32)
         m0 = jnp.full((b, hkv, g, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, qc), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(kv_step, (acc0, m0, l0), (kb, vb, kpb))
@@ -111,14 +111,17 @@ def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos,
     qg = jnp.moveaxis(qg, 1, 0)                      # (nq, B, qc, Hkv, G, D)
     qpb = q_pos.reshape(sq // qc, qc)
     out = jax.lax.map(q_block, (qg, qpb))            # (nq, B, qc, Hkv, G, D)
-    out = jnp.moveaxis(out, 0, 1).reshape(b, sq, hq, d)
+    out = jnp.moveaxis(out, 0, 1).reshape(b, sq, hq, dv)
     return out.astype(q.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
-                    q_pos=None, kv_pos=None, impl: str = "reference"):
-    """Blocked attention. q: (B,Sq,Hq,D), k/v: (B,Skv,Hkv,D) -> (B,Sq,Hq,D)."""
+                    q_pos=None, kv_pos=None, scale: Optional[float] = None,
+                    impl: str = "reference"):
+    """Blocked attention. q: (B,Sq,Hq,D), k: (B,Skv,Hkv,D), v:
+    (B,Skv,Hkv,Dv) -> (B,Sq,Hq,Dv); scores scaled by ``scale`` (default
+    D**-0.5)."""
     b, sq, hq, d = q.shape
     skv = k.shape[1]
     if q_pos is None:
@@ -127,13 +130,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
         kv_pos = jnp.arange(skv)
     if impl == "oracle":
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                        q_pos=q_pos, kv_pos=kv_pos)
+                                        q_pos=q_pos, kv_pos=kv_pos,
+                                        scale=scale)
     if impl == "pallas":
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_pos=q_pos, kv_pos=kv_pos)
+                                      q_pos=q_pos, kv_pos=kv_pos, scale=scale)
     return _flash_reference(q, k, v, causal=causal, window=window,
-                            q_pos=q_pos, kv_pos=kv_pos)
+                            q_pos=q_pos, kv_pos=kv_pos, scale=scale)
 
 
 # --------------------------------------------------------------------------- #

@@ -35,11 +35,13 @@ def attention_mask(q_pos, kv_pos, *, causal: bool, window: Optional[int]):
 
 def flash_attention_ref(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None,
-                        q_pos=None, kv_pos=None):
+                        q_pos=None, kv_pos=None,
+                        scale: Optional[float] = None):
     """Naive attention oracle.
 
-    q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) with Hq % Hkv == 0.
-    Returns (B, Sq, Hq, D) in q.dtype; softmax in fp32.
+    q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv) with
+    Hq % Hkv == 0.  Scores scaled by ``scale`` (default D**-0.5).
+    Returns (B, Sq, Hq, Dv) in q.dtype; softmax in fp32.
     """
     b, sq, hq, d = q.shape
     skv = k.shape[1]
@@ -49,8 +51,9 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
         kv_pos = jnp.arange(skv)
     k = _gqa_expand(k, hq)
     v = _gqa_expand(v, hq)
+    scale = scale or 1.0 / float(d) ** 0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(d).astype(jnp.float32)
+                        k.astype(jnp.float32)) * scale
     mask = attention_mask(q_pos, kv_pos, causal=causal, window=window)
     scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

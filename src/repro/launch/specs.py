@@ -46,14 +46,14 @@ def param_pspec(path: str, ndim: int, rules: Dict[str, Any]) -> P:
     if "norm" in path or last in ("scale", "bias"):
         return P(*([None] * ndim))
 
-    if "/moe/" in path and "/dense/" not in path:
+    if "/moe/" in path and "/shared/" not in path:
         if last == "router":
             return mk("fsdp", None)
         if last in ("wi", "wg"):
             return mk("expert", "fsdp", None)
         if last == "wo":
             return mk("expert", None, "fsdp")
-    if "/ffn/" in path or "/dense/" in path:
+    if "/ffn/" in path or "/shared/" in path:
         if last in ("wi", "wg"):
             return mk("fsdp", "ff")
         if last == "wo":

@@ -72,8 +72,8 @@ def apply_forecaster(params, x, cfg: ModelConfig, *, train: bool = False):
     """x: (B, W, n_features) -> ordered (B, 3) log-gap quantiles."""
     h = x @ params["inp"]["w"] + params["inp"]["b"]
     q_pos = jnp.arange(x.shape[1])
-    h, _, _ = transformer.stack_full(params["stack"], h, cfg, q_pos=q_pos,
-                                     train=train)
+    h, _, _, _ = transformer.stack_full(params["stack"], h, cfg,
+                                        q_pos=q_pos, train=train)
     h = layers.norm_apply(params["norm"], h[:, -1, :], cfg.norm)
     raw = h @ params["head"]["w"] + params["head"]["b"]
     q50 = raw[:, 0]

@@ -89,19 +89,28 @@ def full_attention(p, x, cfg, *, q_pos, causal=True, window=None,
 
 
 def prefill_attention(cfg, seq: int, window: Optional[int]) -> dict:
-    """The path a prefill of ``seq`` tokens takes for causal
-    self-attention, and the score blocks it computes of all it could, per
-    head group: the kernel's block pairs, or the reference's chunk pairs
-    (it computes all).  Span attributes of ``engine.start.compile``."""
+    """The kind of attention (``gqa``, or ``mla`` for latent attention,
+    ``models/mla.py``), the path a prefill of ``seq`` tokens takes for
+    causal self-attention, and the score blocks it computes of all it
+    could, per head group: the kernel's block pairs, or the reference's
+    chunk pairs (it computes all); the cache's bytes a token over every
+    layer (latent attention keeps the latent and the rotated key).  Span
+    attributes of ``engine.start.compile``."""
     impl = ops.choose_flash_impl(cfg.attention_impl, train=False,
                                  self_attn=True, sq=seq, skv=seq)
     if impl == "pallas":
         done, total = flash_attention.block_counts(
-            seq, seq, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-            cfg.dtype, causal=True, window=window)
+            seq, seq, cfg.num_heads, cfg.num_kv_heads, cfg.qk_head_dim,
+            cfg.dtype, causal=True, window=window, dv=cfg.v_head_dim or None)
     else:
         done = total = (seq // ops.pick_chunk(seq, ops.REF_CHUNK)) ** 2
-    return {"prefill_attention": impl, "attention_blocks": f"{done}/{total}"}
+    per_pos = (cfg.kv_lora_rank + cfg.qk_rope_head_dim if cfg.mla
+               else 2 * cfg.kv_dim)
+    n_attn = cfg.layer_pattern.count("A")
+    return {"attention_kind": "mla" if cfg.mla else "gqa",
+            "prefill_attention": impl, "attention_blocks": f"{done}/{total}",
+            "cache_bytes_per_token":
+                per_pos * jnp.dtype(cfg.dtype).itemsize * n_attn}
 
 
 def init_cache(cfg, batch: int, max_seq: int, *, window: Optional[int] = None,

@@ -178,7 +178,7 @@ def encdec_prefill(p, cfg, batch, *, max_seq: int):
     self_kv = jax.tree.map(
         lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))), self_kv)
     caches = {"self": self_kv, "cross": cross_kv}
-    return logits[:, -1, :], caches, s
+    return logits[:, -1, :], caches, s, {}
 
 
 def encdec_decode_step(p, cfg, caches, token, pos):

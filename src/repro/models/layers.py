@@ -6,6 +6,7 @@ where it matters (norms, softmax, router).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -158,12 +159,45 @@ def mlp_apply(params, x, act: str):
 # --------------------------------------------------------------------------- #
 
 
-def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32):
-    """positions: int array (...,) -> cos/sin of shape (..., head_dim//2)."""
+def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32,
+                 *, freqs=None):
+    """positions: int array (...,) -> cos/sin of shape (..., head_dim//2).
+    ``freqs`` replaces the plain inverse frequencies (``yarn_freqs``)."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    if freqs is None:
+        freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
     return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+
+
+def _yarn_dim(rotations: float, dim: int, theta: float, base_len: int) -> float:
+    """The dimension whose wavelength fits ``rotations`` turns in ``base_len``."""
+    return (dim * math.log(base_len / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies of ``dim`` rotated dims (DeepSeek-V2's
+    rule): dims below the correction range keep the extrapolated
+    frequency, dims above it take it divided by ``factor``, with a linear
+    ramp between.  The rule also scales cos and sin by the ratio of the
+    ``mscale`` and ``mscale_all_dim`` factors; only configs where that is
+    1 are built."""
+    lo = max(math.floor(_yarn_dim(yarn.beta_fast, dim, theta,
+                                  yarn.original_max_position_embeddings)), 0)
+    hi = min(math.ceil(_yarn_dim(yarn.beta_slow, dim, theta,
+                                 yarn.original_max_position_embeddings)), dim - 1)
+    if yarn_mscale(yarn.factor, yarn.mscale) != yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim):
+        raise ValueError("YaRN with mscale != mscale_all_dim is not built")
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / yarn.factor
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def apply_rope(x, cos, sin):
@@ -173,6 +207,16 @@ def apply_rope(x, cos, sin):
     c = cos[..., None, :]  # broadcast over heads
     s = sin[..., None, :]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+
+
+def apply_rope_interleaved(x, cos, sin):
+    """Rotary positions over interleaved pairs (x0, x1), (x2, x3), ...
+    (DeepSeek-V2): the pairs are gathered into halves, then rotated as
+    ``apply_rope`` does; the result is in the halves layout."""
+    *lead, d = x.shape
+    x = x.reshape(*lead, d // 2, 2)
+    x = jnp.swapaxes(x, -1, -2).reshape(*lead, d)
+    return apply_rope(x, cos, sin)
 
 
 # --------------------------------------------------------------------------- #

@@ -17,6 +17,8 @@ def init_lm(rng, cfg, *, max_seq: int):
         "blocks": transformer.init_stack(r[1], cfg),
         "norm_f": layers.norm_init(cfg.d_model, cfg.norm, cfg.param_dtype),
     }
+    if transformer.lead_len(cfg):
+        p["lead"] = transformer.init_lead(jax.random.fold_in(r[1], 1), cfg)
     if not cfg.tie_embeddings:
         p["unembed"] = layers.dense_init(r[2], cfg.d_model, cfg.vocab_size,
                                          cfg.param_dtype)
@@ -58,14 +60,21 @@ def _final_norm(p, cfg, x):
         return layers.norm_apply(p["norm_f"], x, cfg.norm)
 
 
-def lm_forward(p, cfg, batch, *, window=None, train=False):
-    """Full-sequence forward: returns (logits, aux, caches)."""
+def _forward(p, cfg, batch, *, window, train):
+    """Full-sequence forward: (final hidden states, aux, caches, routing
+    counts)."""
     x = _inputs_to_x(p, cfg, batch)
     s = x.shape[1]
     q_pos = jnp.arange(s)
-    x, aux, caches = transformer.stack_full(p["blocks"], x, cfg, q_pos=q_pos,
-                                            window=window, train=train)
-    x = _final_norm(p, cfg, x)
+    x, aux, caches, counts = transformer.stack_full(
+        p["blocks"], x, cfg, q_pos=q_pos, window=window, train=train,
+        lead=p.get("lead", ()))
+    return _final_norm(p, cfg, x), aux, caches, counts
+
+
+def lm_forward(p, cfg, batch, *, window=None, train=False):
+    """Full-sequence forward: returns (logits, aux, caches)."""
+    x, aux, caches, _ = _forward(p, cfg, batch, window=window, train=train)
     return _unembed(p, cfg, x), aux, caches
 
 
@@ -93,45 +102,41 @@ def lm_loss(p, cfg, batch, *, window=None):
 
 
 def lm_prefill(p, cfg, batch, *, max_seq: int, window=None):
-    """Prefill: returns (last-token logits, decode caches, next position)."""
-    logits, _, raw = lm_forward(p, cfg, batch, window=window, train=False)
-    s = batch["tokens"].shape[1] if cfg.vision is None else logits.shape[1]
-    caches = _format_caches(cfg, raw, seq_len=logits.shape[1], max_seq=max_seq,
+    """Prefill: returns (last-token logits, decode caches, next position,
+    routing counts).  ``max_seq`` is the decode cache's length: the
+    prompt and the tokens decoded after it (``ModelBundle.max_seq``)."""
+    x, _, raw, counts = _forward(p, cfg, batch, window=window, train=False)
+    seq_len = x.shape[1]
+    caches = _format_caches(cfg, raw, seq_len=seq_len, max_seq=max_seq,
                             window=window)
-    return logits[:, -1, :], caches, logits.shape[1]
+    # the next token needs the last position's logits only
+    return (_unembed(p, cfg, x[:, -1:])[:, 0], caches, seq_len,
+            counts if cfg.moe is not None else {})
 
 
 def _format_caches(cfg, raw_caches, *, seq_len: int, max_seq: int, window):
-    """Pack stack_full cache material into fixed decode cache layout."""
-    metas = transformer._block_meta(cfg)
+    """Pack stack_full cache material into fixed decode cache layout:
+    attention caches (leaves (n_rep, B, S, ...)) padded to ``max_seq``
+    slots, or under a window kept as a ring of ``min(window, max_seq)``."""
     out = []
-    for meta, c in zip(metas, raw_caches):
+    for meta, c in zip(transformer.cache_metas(cfg), raw_caches):
         if meta["kind"] != "A":
             out.append(c)  # recurrent states are already decode-ready
             continue
-        k, v = c["k"], c["v"]                  # (n_rep, B, S, hkv, hd)
         s_cache = min(window, max_seq) if window else max_seq
-        if window and s_cache <= window:
+
+        def pad(a, n):
+            return jnp.pad(a, [(0, 0), (0, 0), (0, n)] + [(0, 0)] * (a.ndim - 3))
+
+        if window and seq_len >= s_cache:
+            # ring layout: absolute position p lives in slot p % w; the
+            # kept suffix starts at `start`, so roll right by start % w.
             w = s_cache
-            if seq_len < w:
-                pad = w - seq_len
-                keep_k = jnp.pad(k[:, :, :seq_len],
-                                 ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-                keep_v = jnp.pad(v[:, :, :seq_len],
-                                 ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-            else:
-                # ring layout: absolute position p lives in slot p % w; the
-                # kept suffix starts at `start`, so roll right by start % w.
-                start = seq_len - w
-                keep_k = jnp.roll(k[:, :, -w:], start % w, axis=2)
-                keep_v = jnp.roll(v[:, :, -w:], start % w, axis=2)
-            out.append({"k": keep_k, "v": keep_v})
+            start = seq_len - w
+            out.append(jax.tree.map(
+                lambda a: jnp.roll(a[:, :, -w:], start % w, axis=2), c))
         else:
-            pad = s_cache - seq_len
-            out.append({
-                "k": jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
-                "v": jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
-            })
+            out.append(jax.tree.map(lambda a: pad(a, s_cache - seq_len), c))
     return out
 
 
@@ -140,6 +145,7 @@ def lm_decode_step(p, cfg, caches, token, pos, *, window=None):
     with jax.named_scope("embed"):
         x = jnp.take(p["embed"], token, axis=0).astype(jnp.dtype(cfg.dtype))
     x, caches = transformer.stack_decode(p["blocks"], x, cfg, pos=pos,
+                                         lead=p.get("lead", ()),
                                          window=window, caches=caches)
     x = _final_norm(p, cfg, x)
     logits = _unembed(p, cfg, x[:, None, :])[:, 0, :]

@@ -1,21 +1,34 @@
-"""Mixture-of-Experts FFN: top-k routing, sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: top-k routing, dropless sorted dispatch.
 
-Design (see DESIGN.md §4): tokens are reshaped into groups of <= ``GROUP``
-tokens; within each group a sort-based dispatch packs tokens into a
-``(experts, capacity, d_model)`` buffer (no GShard one-hot — the (t, E, C)
-one-hot is quadratically larger and does not fit at 32k sequence lengths).
-The buffer's expert dim carries the ``expert`` logical axis, so under the
-production mesh expert compute is expert-parallel over the ``model`` axis
-while groups shard over ``data`` — the classic EP layout, expressed in
-GSPMD.  Capacity overflows drop (Switch-style), bounded by
-``capacity_factor``.
+The router scores every token against all ``num_experts`` experts; each
+token's ``top_k`` (token, expert) pairs are sorted by expert, and the held
+experts' rows go through one grouped matmul per projection
+(``jax.lax.ragged_dot``, group sizes counted from the routing).  The sorted
+buffer has a row for every pair, so no pair is ever dropped, however skewed
+the routing: there is no capacity.  Pairs routed to experts this device
+does not hold sort last, past every group, and add nothing.  A call with no
+more pairs than held experts (a decode step) takes every held expert for
+each token instead, weighted by its gates: the same weights are read, and
+no grouped matmul is expanded for a handful of rows.
 
-Supports Arctic's dense-residual branch (dense FFN parallel to the routed
-experts, summed) and returns the load-balance auxiliary loss.
+A layer holds experts 0 .. ``MoEConfig.experts_held`` - 1 (all of them by
+default): the share chip 0 of an expert-parallel deployment computes.  On
+one device there is no exchange; under a mesh whose rules map experts to
+an axis, ``_moe_ffn_ep`` gives each rank its slice of the held experts and
+combines the partial results with a psum.  The router scores in float32
+at full precision, as the published gate does: a TPU's default for a
+float32 product is one bfloat16 pass, which flips near-tie top-k choices.
+
+A dense FFN that every token passes beside the experts (``shared_ff``:
+Arctic's dense residual, DeepSeek's shared experts) is added once.  The
+layer returns the load-balance auxiliary loss and three counts of its
+routing: pairs routed to held experts, the heaviest held expert's pairs,
+and the held pairs the dispatch left uncomputed (dropped: 0 unless it
+is at fault).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +36,7 @@ import jax.numpy as jnp
 from repro import sharding
 from repro.models import layers
 
-GROUP = 4096  # max tokens per dispatch group
+DISPATCH = "sorted ragged_dot; dense over held at decode"   # moe_dispatch
 
 
 def init_moe(rng, cfg):
@@ -32,132 +45,108 @@ def init_moe(rng, cfg):
     pdt = cfg.param_dtype
     r = jax.random.split(rng, 5)
     ff = m.expert_ff
-    e = m.num_experts
 
     def expert_stack(key, a, b):
-        w = jax.random.truncated_normal(key, -2.0, 2.0, (e, a, b), jnp.float32)
+        w = jax.random.truncated_normal(key, -2.0, 2.0, (m.held, a, b),
+                                        jnp.float32)
         return (w * (a ** -0.5)).astype(pdt)
 
     p = {
-        "router": layers.dense_init(r[0], d, e, "float32"),  # router in fp32
+        "router": layers.dense_init(r[0], d, m.num_experts, "float32"),
         "wi": expert_stack(r[1], d, ff),
         "wo": expert_stack(r[2], ff, d),
     }
     if cfg.act == "swiglu":
         p["wg"] = expert_stack(r[3], d, ff)
-    if m.dense_residual:
-        p["dense"] = layers.mlp_init(r[4], d, m.dense_residual_ff or cfg.d_ff,
-                                     cfg.act, pdt)
+    if m.shared_ff:
+        p["shared"] = layers.mlp_init(r[4], d, m.shared_ff, cfg.act, pdt)
     return p
 
 
-def _capacity(tokens_per_group: int, m) -> int:
-    c = int(tokens_per_group * m.top_k * m.capacity_factor / m.num_experts)
-    return max(m.top_k, min(c, tokens_per_group))
+def start_attrs(cfg) -> Dict[str, str]:
+    """Span attributes of ``engine.start.compile`` for an MoE model."""
+    m = cfg.moe
+    return {"experts_held": f"{m.held}/{m.num_experts}",
+            "moe_dispatch": DISPATCH}
 
 
-def _dispatch_group(x, p, cfg):
-    """x: (t, d) one token group -> (y (t, d), aux_loss scalar)."""
+def zero_counts() -> Dict[str, jax.Array]:
+    return {"moe_pairs_held": jnp.zeros((), jnp.int32),
+            "moe_max_expert_tokens": jnp.zeros((), jnp.int32),
+            "moe_dropped": jnp.zeros((), jnp.int32)}
+
+
+def _route(x, router, m):
+    """x: (t, d) -> top-k weights (t, k) f32, experts (t, k), probs (t, E)."""
+    scores = jnp.dot(x.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(scores, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return top_w, top_i, probs
+
+
+def _ffn(h_in, p, mm):
+    """The experts' hidden activations (SwiGLU or GELU), products by ``mm``."""
+    h = mm(h_in, p["wi"])
+    h = jax.nn.silu(h) * mm(h_in, p["wg"]) if "wg" in p else jax.nn.gelu(h)
+    return h
+
+
+def _experts(x, p, cfg, *, first, held):
+    """The part of experts ``first .. first + held - 1`` for one token set.
+
+    x: (t, d) -> (y (t, d) f32, aux loss, counts).  With no more pairs
+    than held experts (a decode step) every held expert's weights are read
+    once either way, so each token goes through all of them, weighted by
+    its gates (0 where not routed); otherwise the pairs are sorted by
+    expert through the grouped matmuls, each pair computed once."""
     m = cfg.moe
     t, d = x.shape
-    e, k = m.num_experts, m.top_k
-    cap = _capacity(t, m)
+    k, e = m.top_k, m.num_experts
+    top_w, top_i, probs = _route(x, p["router"], m)
 
-    logits = (x.astype(jnp.float32) @ p["router"]).astype(jnp.float32)  # (t, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)                 # (t, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-
-    flat_e = top_i.reshape(t * k)
-    order = jnp.argsort(flat_e)                            # stable
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=e)                # (E,)
-    offsets = jnp.cumsum(counts) - counts                  # exclusive
-    pos_in_e = jnp.arange(t * k) - offsets[sorted_e]
-    keep = pos_in_e < cap
-    slot = jnp.where(keep, sorted_e * cap + pos_in_e, e * cap)
-    tok_idx = order // k
-
-    buf = jnp.zeros((e * cap + 1, d), x.dtype).at[slot].set(x[tok_idx])
-    buf = buf[: e * cap].reshape(e, cap, d)
-    buf = sharding.logical(buf, ("expert", None, None))
-
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    if "wg" in p:
-        h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buf, p["wg"])
+    local = top_i.reshape(t * k) - first
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)          # not held: after all groups
+    sizes = jnp.bincount(group, length=held + 1)[:held]
+    w = jnp.where(mine, top_w.reshape(t * k), 0.0)
+    tok = jnp.arange(t * k) // k
+    if t * k <= held:
+        gate = jnp.zeros((t, held + 1), jnp.float32).at[tok, group].add(w)
+        gated = jnp.zeros((t, held + 1), jnp.int32).at[tok, group].add(1)
+        computed = gated[:, :held].sum()
+        h = _ffn(x, p, lambda a, b: jnp.einsum("td,edf->tef", a, b))
+        out = jnp.einsum("tef,efd->ted", h, p["wo"],
+                         preferred_element_type=jnp.float32)
+        y = jnp.einsum("ted,te->td", out, gate[:, :held])
     else:
-        h = jax.nn.gelu(h)
-    out = jnp.einsum("ecf,efd->ecd", h, p["wo"])
-    out = sharding.logical(out, ("expert", None, None))
+        order = jnp.argsort(group, stable=True)
+        rows = x[tok[order]]                       # (t*k, d), by expert
 
-    out_flat = jnp.concatenate(
-        [out.reshape(e * cap, d), jnp.zeros((1, d), out.dtype)], axis=0)
-    y_sorted = out_flat[slot]                              # (t*k, d)
-    w_sorted = (top_w.reshape(t * k)[order] * keep).astype(jnp.float32)
-    y = jnp.zeros((t, d), jnp.float32).at[tok_idx].add(
-        y_sorted.astype(jnp.float32) * w_sorted[:, None])
+        def mm(a, b):
+            return jax.lax.ragged_dot(a, b, sizes)
 
-    # load-balance aux loss (Switch): E * sum_e f_e * p_e
-    f = counts.astype(jnp.float32) / (t * k)
-    pbar = probs.mean(axis=0)
-    aux = e * jnp.sum(f * pbar)
-    return y.astype(x.dtype), aux
+        out = mm(_ffn(rows, p, mm), p["wo"])
+        live = jnp.arange(t * k) < sizes.sum()    # rows past the groups: none
+        computed = jnp.sum(live & mine[order])
+        out = jnp.where(live[:, None], out.astype(jnp.float32), 0.0)
+        y = jnp.zeros((t, d), jnp.float32).at[tok[order]].add(
+            out * w[order][:, None])
 
-
-def _dispatch_group_local(x, p_local, cfg, *, rank, e_local):
-    """Expert-parallel local dispatch: this shard owns experts
-    [rank*e_local, (rank+1)*e_local).  Routing is computed over ALL experts
-    (router weights are replicated, x is replicated over the model axis so
-    every rank computes identical routing); only locally-owned assignments
-    are dispatched; the cross-rank combine is the caller's psum.
-    """
-    m = cfg.moe
-    t, d = x.shape
-    e, k = m.num_experts, m.top_k
-    cap = _capacity(t, m)
-
-    logits = (x.astype(jnp.float32) @ p_local["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-
-    flat_e = top_i.reshape(t * k)
-    flat_w = top_w.reshape(t * k)
-    owned = (flat_e >= rank * e_local) & (flat_e < (rank + 1) * e_local)
-    local_e = jnp.where(owned, flat_e - rank * e_local, e_local)  # sentinel
-    order = jnp.argsort(local_e)
-    sorted_e = local_e[order]
-    counts = jnp.bincount(local_e, length=e_local + 1)
-    offsets = jnp.cumsum(counts) - counts
-    pos_in_e = jnp.arange(t * k) - offsets[sorted_e]
-    keep = (pos_in_e < cap) & (sorted_e < e_local)
-    slot = jnp.where(keep, sorted_e * cap + pos_in_e, e_local * cap)
-    tok_idx = order // k
-
-    buf = jnp.zeros((e_local * cap + 1, d), x.dtype).at[slot].set(x[tok_idx])
-    buf = buf[: e_local * cap].reshape(e_local, cap, d)
-    h = jnp.einsum("ecd,edf->ecf", buf, p_local["wi"])
-    if "wg" in p_local:
-        h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buf, p_local["wg"])
-    else:
-        h = jax.nn.gelu(h)
-    out = jnp.einsum("ecf,efd->ecd", h, p_local["wo"])
-    out_flat = jnp.concatenate(
-        [out.reshape(e_local * cap, d), jnp.zeros((1, d), out.dtype)], axis=0)
-    y_sorted = out_flat[slot]
-    w_sorted = (flat_w[order] * keep).astype(jnp.float32)
-    y = jnp.zeros((t, d), jnp.float32).at[tok_idx].add(
-        y_sorted.astype(jnp.float32) * w_sorted[:, None])
-
-    # aux loss: identical on every rank (global routing stats) -> replicated
-    f = jnp.bincount(flat_e, length=e).astype(jnp.float32) / (t * k)
+    # load-balance aux loss (Switch): E * sum_e f_e * p_e, over all experts
+    f = jnp.bincount(top_i.reshape(-1), length=e).astype(jnp.float32) / (t * k)
     aux = e * jnp.sum(f * probs.mean(axis=0))
-    return y.astype(x.dtype), aux
+    counts = {"moe_pairs_held": sizes.sum().astype(jnp.int32),
+              "moe_max_expert_tokens": sizes.max().astype(jnp.int32),
+              "moe_dropped": (mine.sum() - computed).astype(jnp.int32)}
+    return y, aux, counts
 
 
-def _moe_ffn_ep(p, x, cfg, rules, mesh) -> Tuple[jax.Array, jax.Array]:
+def _moe_ffn_ep(p, x, cfg, rules, mesh):
     """Explicit expert-parallel MoE via shard_map (EXPERIMENTS.md §Perf
-    iteration 2): GSPMD replicates the sort-based dispatch (≈26 GB/layer of
+    iteration 2): GSPMD replicates the dispatch (≈26 GB/layer of
     collectives on qwen3-moe prefill); manual EP needs only the combine
     all-reduce of the token activations (≈0.27 GB/layer)."""
     from jax.sharding import PartitionSpec as P
@@ -182,43 +171,41 @@ def _moe_ffn_ep(p, x, cfg, rules, mesh) -> Tuple[jax.Array, jax.Array]:
         seq_ax = None
     msize = mesh.shape[model_ax] if isinstance(model_ax, str) else 1
     m = cfg.moe
-    e_local = m.num_experts // msize
+    e_local = m.held // msize
 
     moe_parts = {k: p[k] for k in ("router", "wi", "wg", "wo") if k in p}
     spec_parts = {k: (P(None, None) if k == "router" else P(model_ax, None, None))
                   for k in moe_parts}
-    dense = p.get("dense")
-    dense_spec = None
-    if dense is not None:
+    shared = p.get("shared")
+    shared_spec = None
+    if shared is not None:
         ff_ax = rules.get("ff")
-        dense_spec = {k: (P(None, ff_ax) if k in ("wi", "wg") else P(ff_ax, None))
-                      for k in dense}
+        shared_spec = {k: (P(None, ff_ax) if k in ("wi", "wg") else P(ff_ax, None))
+                       for k in shared}
 
-    def local_fn(parts, dense_local, xl):
+    def local_fn(parts, shared_local, xl):
         rank = jax.lax.axis_index(model_ax) if msize > 1 else 0
         b, s, d = xl.shape
-        t = b * s
-        gs = min(GROUP, t)
-        g = t // gs if t % gs == 0 else 1
-        gs = t // g
-        xg = xl.reshape(g, gs, d)
-        y, aux = jax.vmap(lambda xx: _dispatch_group_local(
-            xx, parts, cfg, rank=rank, e_local=e_local))(xg)
-        y = y.reshape(b, s, d).astype(jnp.float32)
-        if dense_local is not None:
-            # dense residual branch: ff dim sharded on the same axis; its
-            # partial sums ride the same combine all-reduce
-            h = xl @ dense_local["wi"]
-            if "wg" in dense_local:
-                h = jax.nn.silu(h) * (xl @ dense_local["wg"])
-            else:
-                h = jax.nn.gelu(h)
-            y = y + (h @ dense_local["wo"]).astype(jnp.float32)
+        y, aux, counts = _experts(xl.reshape(b * s, d), parts, cfg,
+                                  first=rank * e_local,
+                                  held=e_local)
+        y = y.reshape(b, s, d)
+        if shared_local is not None:
+            # shared branch: ff dim sharded on the same axis; its partial
+            # sums ride the same combine all-reduce
+            y = y + layers.mlp_apply(shared_local, xl, cfg.act).astype(
+                jnp.float32)
         y = jax.lax.psum(y, model_ax)
-        return y.astype(xl.dtype), aux.mean()
+        counts = {"moe_pairs_held": jax.lax.psum(counts["moe_pairs_held"],
+                                                 model_ax),
+                  "moe_max_expert_tokens": jax.lax.pmax(
+                      counts["moe_max_expert_tokens"], model_ax),
+                  "moe_dropped": jax.lax.psum(counts["moe_dropped"],
+                                              model_ax)}
+        return y.astype(xl.dtype), aux, counts
 
-    in_specs = (spec_parts, dense_spec, P(batch_ax, seq_ax, None))
-    out_specs = (P(batch_ax, seq_ax, None), P())
+    in_specs = (spec_parts, shared_spec, P(batch_ax, seq_ax, None))
+    out_specs = (P(batch_ax, seq_ax, None), P(), P())
     try:
         sm = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
@@ -226,16 +213,16 @@ def _moe_ffn_ep(p, x, cfg, rules, mesh) -> Tuple[jax.Array, jax.Array]:
         from jax.experimental.shard_map import shard_map as _shard_map
         sm = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs, check_rep=False)
-    y, aux = sm(moe_parts, dense, x)
-    return y, cfg.moe.router_aux_weight * aux
+    y, aux, counts = sm(moe_parts, shared, x)
+    return y, cfg.moe.router_aux_weight * aux, counts
 
 
-def moe_ffn(p, x, cfg) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (y (B, S, d), aux_loss scalar).
+def moe_ffn(p, x, cfg) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, d) -> (y (B, S, d), aux_loss scalar, routing counts).
 
     Uses the explicit shard_map expert-parallel path whenever an active
-    sharding context maps experts to a mesh axis; otherwise the pure-GSPMD
-    single-device path (CPU smoke tests, unsharded serving engines).
+    sharding context maps experts to a mesh axis; otherwise the single-
+    device path (CPU smoke tests, serving engines).
     """
     ctx = sharding.current_rules_and_mesh()
     if ctx is not None:
@@ -247,14 +234,10 @@ def moe_ffn(p, x, cfg) -> Tuple[jax.Array, jax.Array]:
         if rules.get("expert") and x.shape[0] * x.shape[1] >= 2048:
             return _moe_ffn_ep(p, x, cfg, rules, mesh)
     b, s, d = x.shape
-    t = b * s
-    gs = min(GROUP, t)
-    g = t // gs
-    xg = x.reshape(g, gs, d) if g * gs == t else x.reshape(1, t, d)
-    xg = sharding.logical(xg, ("moe_group", None, None))
-    y, aux = jax.vmap(lambda xx: _dispatch_group(xx, p, cfg))(xg)
+    m = cfg.moe
+    y, aux, counts = _experts(x.reshape(b * s, d), p, cfg, first=0,
+                              held=m.held)
     y = y.reshape(b, s, d)
-    out = y
-    if "dense" in p:
-        out = out + layers.mlp_apply(p["dense"], x, cfg.act)
-    return out, cfg.moe.router_aux_weight * aux.mean()
+    if "shared" in p:
+        y = y + layers.mlp_apply(p["shared"], x, cfg.act).astype(jnp.float32)
+    return y.astype(x.dtype), m.router_aux_weight * aux, counts

@@ -39,7 +39,8 @@ class ModelBundle:
     window: Optional[int]
     init: Callable[[jax.Array], Any]
     loss: Callable[[Any, Dict[str, jax.Array]], Tuple[jax.Array, Dict]]
-    prefill: Callable[[Any, Dict[str, jax.Array]], Tuple[jax.Array, Any, int]]
+    # (last logits, decode caches, next position, routing counts)
+    prefill: Callable[[Any, Dict[str, jax.Array]], Tuple[jax.Array, Any, int, Dict]]
     decode_step: Callable[[Any, Any, jax.Array, jax.Array], Tuple[jax.Array, Any]]
 
     # ----------------------------------------------------------------- #

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import sharding
-from repro.models import attention, layers, mamba, moe, xlstm
+from repro.models import attention, layers, mamba, mla, moe, xlstm
 
 
 # --------------------------------------------------------------------------- #
@@ -29,27 +29,47 @@ from repro.models import attention, layers, mamba, moe, xlstm
 # --------------------------------------------------------------------------- #
 
 
+def lead_len(cfg) -> int:
+    """Leading dense layers (``MoEConfig.first_dense``), stacked apart
+    from the periods that follow them."""
+    return cfg.moe.first_dense if cfg.moe is not None else 0
+
+
 def period_len(cfg) -> int:
     p = len(cfg.block_pattern)
     if cfg.moe is not None:
         p = math.lcm(p, cfg.moe.every_n_layers)
-    if cfg.num_layers % p:
+    if (cfg.num_layers - lead_len(cfg)) % p:
         raise ValueError(
-            f"{cfg.name}: num_layers={cfg.num_layers} not a multiple of "
-            f"pattern period {p}")
+            f"{cfg.name}: num_layers={cfg.num_layers} less {lead_len(cfg)} "
+            f"leading layers not a multiple of pattern period {p}")
     return p
 
 
 def _block_meta(cfg) -> List[Dict[str, Any]]:
     """Per-position-in-period: mixer kind + ffn kind."""
-    per = period_len(cfg)
+    per, lead = period_len(cfg), lead_len(cfg)
     moe_mask = cfg.moe_layer_mask()
     pat = cfg.layer_pattern
     out = []
-    for i in range(per):
+    for i in range(lead, lead + per):
         ffn = "moe" if moe_mask[i] else ("dense" if cfg.d_ff else "none")
         out.append({"kind": pat[i], "ffn": ffn})
     return out
+
+
+def _lead_meta(cfg) -> List[Dict[str, Any]]:
+    """The leading dense layers' one stacked position, if any."""
+    lead = lead_len(cfg)
+    kinds = set(cfg.layer_pattern[:lead])
+    if len(kinds) > 1:
+        raise ValueError(f"{cfg.name}: leading layers of kinds {kinds}")
+    return [{"kind": k, "ffn": "dense"} for k in kinds]
+
+
+def cache_metas(cfg) -> List[Dict[str, Any]]:
+    """Metas of the decode caches' list: leading layers, then periods."""
+    return _lead_meta(cfg) + _block_meta(cfg)
 
 
 # --------------------------------------------------------------------------- #
@@ -62,7 +82,8 @@ def _init_block(rng, cfg, meta) -> Dict[str, Any]:
     p: Dict[str, Any] = {"norm1": layers.norm_init(cfg.d_model, cfg.norm, cfg.param_dtype)}
     kind = meta["kind"]
     if kind == "A":
-        p["attn"] = attention.init_attention(r[0], cfg)
+        p["attn"] = (mla.init_mla(r[0], cfg) if cfg.mla
+                     else attention.init_attention(r[0], cfg))
     elif kind == "M":
         p["ssm"] = mamba.init_mamba(r[0], cfg)
     elif kind == "L":
@@ -80,17 +101,24 @@ def _init_block(rng, cfg, meta) -> Dict[str, Any]:
     return p
 
 
+def _init_stacked(rng, cfg, metas, n_rep) -> List[Any]:
+    stacked = []
+    for pos, meta in enumerate(metas):
+        keys = jax.random.split(jax.random.fold_in(rng, pos), n_rep)
+        stacked.append(jax.vmap(lambda k, m=meta: _init_block(k, cfg, m))(keys))
+    return stacked
+
+
 def init_stack(rng, cfg) -> List[Any]:
     """Returns a list (one entry per period position) of param trees whose
-    leaves are stacked over the ``n_rep = L / period`` repeats."""
-    per = period_len(cfg)
-    metas = _block_meta(cfg)
-    n_rep = cfg.num_layers // per
-    stacked = []
-    for pos in range(per):
-        keys = jax.random.split(jax.random.fold_in(rng, pos), n_rep)
-        stacked.append(jax.vmap(lambda k, m=metas[pos]: _init_block(k, cfg, m))(keys))
-    return stacked
+    leaves are stacked over the ``n_rep = (L - lead) / period`` repeats."""
+    n_rep = (cfg.num_layers - lead_len(cfg)) // period_len(cfg)
+    return _init_stacked(rng, cfg, _block_meta(cfg), n_rep)
+
+
+def init_lead(rng, cfg) -> List[Any]:
+    """The leading dense layers' params, stacked over them ([] if none)."""
+    return _init_stacked(rng, cfg, _lead_meta(cfg), lead_len(cfg))
 
 
 # --------------------------------------------------------------------------- #
@@ -99,7 +127,7 @@ def init_stack(rng, cfg) -> List[Any]:
 
 
 def _apply_ffn(p, x, cfg):
-    aux = jnp.zeros((), jnp.float32)
+    aux, counts = jnp.zeros((), jnp.float32), moe.zero_counts()
     if "ffn" in p:
         with jax.named_scope("norm"):
             h = layers.norm_apply(p["norm2"], x, cfg.norm)
@@ -110,9 +138,9 @@ def _apply_ffn(p, x, cfg):
         with jax.named_scope("norm"):
             h = layers.norm_apply(p["norm2"], x, cfg.norm)
         with jax.named_scope("moe"):
-            y, aux = moe.moe_ffn(p["moe"], h, cfg)
+            y, aux, counts = moe.moe_ffn(p["moe"], h, cfg)
         x = x + y
-    return x, aux
+    return x, aux, counts
 
 
 def _mixer_scope(kind: str) -> str:
@@ -128,13 +156,15 @@ def _block_full(p, x, cfg, meta, q_pos, window, states, train):
     with jax.named_scope(_mixer_scope(kind)):
         y, cache = _mixer_full(p, h, cfg, kind, q_pos, window, states, train)
     x = x + y
-    x, aux = _apply_ffn(p, x, cfg)
+    x, aux, counts = _apply_ffn(p, x, cfg)
     x = sharding.logical(x, ("batch", "seq", "embed"))
-    return x, aux, cache
+    return x, aux, cache, counts
 
 
 def _mixer_full(p, h, cfg, kind, q_pos, window, states, train):
-    if kind == "A":
+    if kind == "A" and cfg.mla:
+        y, cache = mla.mla_full(p["attn"], h, cfg, q_pos=q_pos, train=train)
+    elif kind == "A":
         # context-parallel fallback (§Perf iter. 3): tokens sharded over the
         # model axis through the attention block when heads don't divide it
         h = sharding.logical(h, ("batch", "attn_seq", None))
@@ -162,12 +192,14 @@ def _block_decode(p, x, cfg, meta, pos, window, cache):
         y, cache = _mixer_decode(p, h, cfg, kind, pos, window, cache)
     x = x + y
     x3 = x[:, None, :]
-    x3, _ = _apply_ffn(p, x3, cfg)
+    x3, _, _ = _apply_ffn(p, x3, cfg)
     return x3[:, 0, :], cache
 
 
 def _mixer_decode(p, h, cfg, kind, pos, window, cache):
-    if kind == "A":
+    if kind == "A" and cfg.mla:
+        y, cache = mla.mla_decode(p["attn"], h, cache, pos, cfg)
+    elif kind == "A":
         y, cache = attention.decode_attention(
             p["attn"], h, cache, pos, cfg, window=window,
             use_rope=cfg.encoder is None)
@@ -185,46 +217,57 @@ def _mixer_decode(p, h, cfg, kind, pos, window, cache):
 # --------------------------------------------------------------------------- #
 
 
-def stack_full(stack_params, x, cfg, *, q_pos, window=None, train=False):
-    """x: (B, S, d) -> (x, aux_loss, caches).
-
-    caches: list per period position; each leaf stacked over n_rep.
-    """
-    metas = _block_meta(cfg)
-
+def _scan_full(stack_params, metas, n_rep, x, cfg, q_pos, window, train):
+    """One stacked group of layers over the full sequence.  Returns
+    (x, aux, caches, counts)."""
     def period_fn(carry, period_params):
-        x, aux = carry
+        x, aux, counts = carry
         caches = []
         for pos, meta in enumerate(metas):
-            x, a, c = _block_full(period_params[pos], x, cfg, meta, q_pos,
-                                  window, None, train)
+            x, a, c, n = _block_full(period_params[pos], x, cfg, meta, q_pos,
+                                     window, None, train)
             aux = aux + a
+            counts = jax.tree.map(jnp.add, counts, n)
             caches.append(c)
-        return (x, aux), tuple(caches)
+        return (x, aux, counts), tuple(caches)
 
     fn = (jax.checkpoint(period_fn, prevent_cse=False)
           if (train and cfg.remat) else period_fn)
+    carry = (x, jnp.zeros((), jnp.float32), moe.zero_counts())
     if cfg.unroll_layers:
         # roofline mode: python loop so XLA cost_analysis sees every layer
-        carry = (x, jnp.zeros((), jnp.float32))
         all_caches = []
-        n_rep = cfg.num_layers // len(metas)
         for i in range(n_rep):
             pp = jax.tree.map(lambda a: a[i], tuple(stack_params))
             carry, caches_i = fn(carry, pp)
             all_caches.append(caches_i)
-        (x, aux) = carry
         caches = jax.tree.map(lambda *xs: jnp.stack(xs), *all_caches)
-        return x, aux, list(caches)
-    (x, aux), caches = jax.lax.scan(
-        fn, (x, jnp.zeros((), jnp.float32)), tuple(stack_params))
-    return x, aux, list(caches)
+    else:
+        carry, caches = jax.lax.scan(fn, carry, tuple(stack_params))
+    x, aux, counts = carry
+    return x, aux, list(caches), counts
 
 
-def stack_decode(stack_params, x, cfg, *, pos, window=None, caches=None):
-    """x: (B, d) one token -> (x, new_caches)."""
-    metas = _block_meta(cfg)
+def stack_full(stack_params, x, cfg, *, q_pos, window=None, train=False,
+               lead=()):
+    """x: (B, S, d) -> (x, aux_loss, caches, routing counts).
 
+    ``lead`` holds the leading dense layers' params (``init_lead``), run
+    before the periods.  caches: a list by ``cache_metas`` position, each
+    leaf stacked over that position's layers; counts: ``moe_ffn``'s,
+    summed over the layers."""
+    n_rep = (cfg.num_layers - lead_len(cfg)) // period_len(cfg)
+    aux, caches, counts = 0.0, [], moe.zero_counts()
+    if lead:
+        x, aux, caches, counts = _scan_full(lead, _lead_meta(cfg),
+                                            lead_len(cfg), x, cfg, q_pos,
+                                            window, train)
+    x, a, c, n = _scan_full(stack_params, _block_meta(cfg), n_rep, x, cfg,
+                            q_pos, window, train)
+    return x, aux + a, caches + c, jax.tree.map(jnp.add, counts, n)
+
+
+def _scan_decode(stack_params, metas, n_rep, x, cfg, pos, window, caches):
     def period_fn(x, xs):
         period_params, period_caches = xs
         new = []
@@ -235,7 +278,6 @@ def stack_decode(stack_params, x, cfg, *, pos, window=None, caches=None):
         return x, tuple(new)
 
     if cfg.unroll_layers:
-        n_rep = cfg.num_layers // len(metas)
         outs = []
         for i in range(n_rep):
             xs_i = jax.tree.map(lambda a: a[i],
@@ -248,14 +290,31 @@ def stack_decode(stack_params, x, cfg, *, pos, window=None, caches=None):
     return x, list(new_caches)
 
 
+def stack_decode(stack_params, x, cfg, *, pos, window=None, caches=None,
+                 lead=()):
+    """x: (B, d) one token -> (x, new_caches); ``lead`` as in
+    ``stack_full``."""
+    n_rep = (cfg.num_layers - lead_len(cfg)) // period_len(cfg)
+    new = []
+    if lead:
+        x, new = _scan_decode(lead, _lead_meta(cfg), lead_len(cfg), x, cfg,
+                              pos, window, caches[:len(lead)])
+        caches = caches[len(lead):]
+    x, rest = _scan_decode(stack_params, _block_meta(cfg), n_rep, x, cfg,
+                           pos, window, caches)
+    return x, new + rest
+
+
 def init_decode_caches(cfg, batch: int, max_seq: int, *, window=None):
-    """Allocate per-period-position caches, stacked over n_rep."""
-    per = period_len(cfg)
-    metas = _block_meta(cfg)
-    n_rep = cfg.num_layers // per
+    """Allocate caches by ``cache_metas`` position, each stacked over that
+    position's layers."""
+    n_rep = (cfg.num_layers - lead_len(cfg)) // period_len(cfg)
+    reps = [lead_len(cfg)] * len(_lead_meta(cfg)) + [n_rep] * len(_block_meta(cfg))
     out = []
-    for meta in metas:
-        if meta["kind"] == "A":
+    for meta, n in zip(cache_metas(cfg), reps):
+        if meta["kind"] == "A" and cfg.mla:
+            one = mla.init_cache(cfg, batch, max_seq)
+        elif meta["kind"] == "A":
             one = attention.init_cache(cfg, batch, max_seq, window=window)
         elif meta["kind"] == "M":
             one = mamba.init_mamba_state(cfg, batch)
@@ -263,5 +322,6 @@ def init_decode_caches(cfg, batch: int, max_seq: int, *, window=None):
             one = xlstm.init_mlstm_state(cfg, batch)
         else:
             one = xlstm.init_slstm_state(cfg, batch)
-        out.append(jax.tree.map(lambda a: jnp.broadcast_to(a, (n_rep, *a.shape)), one))
+        out.append(jax.tree.map(
+            lambda a, n=n: jnp.broadcast_to(a, (n, *a.shape)), one))
     return out

@@ -38,7 +38,7 @@ import numpy as np
 
 from repro import compile_cache, spans
 from repro.core.lifecycle import Breakdown, Phase
-from repro.models import attention, registry
+from repro.models import attention, moe, registry
 from repro.training import checkpoint
 
 
@@ -114,11 +114,13 @@ class InferenceEngine:
     """One 'serverless function' instance (container analogue)."""
 
     def __init__(self, arch: str, *, smoke: bool = True, max_seq: int = 128,
-                 batch: int = 1, store: Optional[SnapshotStore] = None,
+                 batch: int = 1, decode_steps: int = 8,
+                 store: Optional[SnapshotStore] = None,
                  runtime: str = "python-jit", seed: int = 0):
         self.arch = arch
         self.smoke = smoke
-        self.max_seq = max_seq
+        self.max_seq = max_seq              # the prompt's length
+        self.decode_steps = decode_steps    # tokens the cache has room for
         self.batch = batch
         self.store = store
         self.runtime = runtime
@@ -134,8 +136,15 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------ #
     @property
+    def cache_len(self) -> int:
+        """Positions the decode cache covers: the prompt and every decoded
+        token (a window layer keeps ``min(cache_len, window)`` slots)."""
+        return self.max_seq + self.decode_steps
+
+    @property
     def key(self) -> str:
-        return f"{self.arch}_s{self.max_seq}_b{self.batch}_{self.smoke}"
+        return (f"{self.arch}_s{self.max_seq}_c{self.cache_len}"
+                f"_b{self.batch}_{self.smoke}")
 
     def package_bytes(self) -> int:
         return _tree_bytes(self.params) if self.params is not None else 0
@@ -165,7 +174,7 @@ class InferenceEngine:
                         from_snapshot=use_snap):
             with spans.span("engine.start.build") as build:
                 self.bundle = registry.build_arch(self.arch, smoke=self.smoke,
-                                                  max_seq=self.max_seq)
+                                                  max_seq=self.cache_len)
             with spans.span("engine.start.weights") as weights:
                 if use_snap:
                     with spans.span("engine.start.weights.read"):
@@ -189,11 +198,14 @@ class InferenceEngine:
             self.last_start = StartPath(from_snapshot=use_snap,
                                         executable_hit=exe is not None)
             cfg = self.bundle.cfg
-            attn = (attention.prefill_attention(cfg, self.max_seq,
-                                                self.bundle.window)
-                    if "A" in cfg.layer_pattern else {})
+            attrs = {}
+            if "A" in cfg.layer_pattern:
+                attrs.update(attention.prefill_attention(
+                    cfg, self.max_seq, self.bundle.window))
+            if cfg.moe is not None:
+                attrs.update(moe.start_attrs(cfg))
             with spans.span("engine.start.compile",
-                            executable_hit=exe is not None, **attn) as code:
+                            executable_hit=exe is not None, **attrs) as code:
                 with compile_cache.counting() as cache:
                     if exe is not None:
                         self._prefill_c, self._decode_c = exe
@@ -240,6 +252,9 @@ class InferenceEngine:
         prefill, then per step the previous token fetched to the host and
         the next step dispatched.  ``ServeStats`` holds span durations."""
         assert self.warm, "cold engine — call cold_start() first"
+        if decode_steps > self.decode_steps:
+            raise ValueError(f"{self.arch}: {decode_steps} decode steps asked "
+                             f"of a cache with room for {self.decode_steps}")
         stats = ServeStats()
         with spans.span("engine.run", prompt_tokens=int(tokens.shape[1]),
                         decode_steps=decode_steps) as run:
@@ -248,8 +263,11 @@ class InferenceEngine:
                 if extras:
                     batch.update({k: jnp.asarray(v) for k, v in extras.items()})
             with spans.span("engine.prefill_run") as prefill:
-                logits, caches, pos = self._prefill_c(self.params, batch)
+                logits, caches, _, counts = self._prefill_c(self.params, batch)
                 jax.block_until_ready(logits)
+                if counts:              # routing of this call, over the MoE layers
+                    prefill.attrs.update(
+                        {k: int(v) for k, v in jax.device_get(counts).items()})
             out = []
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             with spans.span("engine.decode") as decode:
@@ -292,7 +310,7 @@ def fuse_chain(engines: List[InferenceEngine], *, decode_steps: int = 4):
         tokens = batch["tokens"]
         for bundle, p in zip(bundles, params_list):
             tokens = tokens % bundle.cfg.vocab_size
-            logits, caches, pos = bundle.prefill(p, {"tokens": tokens})
+            logits, caches, _, _ = bundle.prefill(p, {"tokens": tokens})
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             outs = []
             pp = jnp.asarray(tokens.shape[1], jnp.int32)

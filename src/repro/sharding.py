@@ -146,7 +146,6 @@ def make_rules(cfg, shape, mesh: Mesh, *, seq_shard: Optional[bool] = None) -> D
     if cfg.xlstm is not None:
         d_in = int(cfg.xlstm.proj_factor * cfg.d_model)
         rules["xlstm_inner"] = fits(d_in, model)
-    rules["moe_group"] = rules["batch"]
     # §Perf iteration 3: context-parallel attention fallback.  When heads
     # are not divisible by the model axis (qwen2.5's 40, whisper's 20,
     # internvl2's 14), GSPMD replicates the whole attention block across

@@ -23,12 +23,17 @@ The names, by layer (``NAMES``):
           .compile / .run         a fresh init: compile, then run
           .read / .put            a snapshot: read to host, then put
         engine.start.compile      prefill and decode executables (code_init;
-                                  cache_hits, cache_misses, executable_hit)
+                                  cache_hits, cache_misses, executable_hit;
+                                  attention_kind, prefill_attention,
+                                  attention_blocks, cache_bytes_per_token;
+                                  experts_held, moe_dispatch)
         engine.start.save         the parameter snapshot written
     engine.run                    InferenceEngine.serve (prompt_tokens,
                                   decode_steps)
       engine.upload               the prompt to the device
       engine.prefill_run          prefill dispatched and waited for
+                                  (moe_pairs_held, moe_max_expert_tokens,
+                                  moe_dropped)
       engine.decode               the decode loop (ServeStats.decode_s)
         engine.token_fetch        the previous token to the host, per step
         engine.step_dispatch      index add, decode program, argmax, per step

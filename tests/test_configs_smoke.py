@@ -63,7 +63,7 @@ def test_prefill_decode_shapes_no_nans(arch):
     bundle = registry.build(cfg, max_seq=S + 8)
     params = bundle.init(jax.random.key(0))
     batch = _batch(cfg, with_labels=False)
-    logits, caches, pos = jax.jit(bundle.prefill)(params, batch)
+    logits, caches, pos, _ = jax.jit(bundle.prefill)(params, batch)
     assert logits.shape == (B, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
     tok = jnp.argmax(logits, -1).astype(jnp.int32)

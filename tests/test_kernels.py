@@ -31,20 +31,26 @@ ATTN_CASES = [
     (3, 256, 256, 6, 2, 48, True, 128),
     (1, 256, 256, 8, 2, 80, True, None),    # danube's G=4 and head_dim 80
     (1, 512, 512, 8, 2, 80, True, 256),     # the same, windowed
+    # (..., v head dim, softmax scale): latent attention's expanded prefill
+    (1, 256, 256, 4, 4, 192, True, None, 128, 0.114721),   # DeepSeek-V2
+    (2, 128, 128, 4, 4, 96, True, None, 64, 0.25),
 ]
 
 
 @pytest.mark.parametrize("case", ATTN_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_matches_oracle(case, dtype):
-    b, sq, skv, hq, hkv, d, causal, window = case
+    b, sq, skv, hq, hkv, d, causal, window, *rest = case
+    dv, scale = rest or (d, None)
     q = jnp.asarray(RNG.normal(size=(b, sq, hq, d)), dtype)
     k = jnp.asarray(RNG.normal(size=(b, skv, hkv, d)), dtype)
-    v = jnp.asarray(RNG.normal(size=(b, skv, hkv, d)), dtype)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    got_pallas = flash_attention_pallas(q, k, v, causal=causal, window=window)
+    v = jnp.asarray(RNG.normal(size=(b, skv, hkv, dv)), dtype)
+    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window,
+                                   scale=scale)
+    got_pallas = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                        scale=scale)
     got_ref = ops.flash_attention(q, k, v, causal=causal, window=window,
-                                  impl="reference")
+                                  scale=scale, impl="reference")
     np.testing.assert_allclose(np.asarray(got_pallas, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
     np.testing.assert_allclose(np.asarray(got_ref, np.float32),
@@ -191,12 +197,30 @@ def test_prefill_attention_span_attributes(monkeypatch):
 
     # h2o-danube-1.8b's attention (chipbench/configs/h2o-danube-1.8b.json)
     cfg = ModelConfig(name="danube", family="dense", source="-",
-                      num_heads=32, num_kv_heads=8, head_dim=80)
+                      num_layers=24, num_heads=32, num_kv_heads=8,
+                      head_dim=80)
+    cache = {"attention_kind": "gqa", "cache_bytes_per_token": 61440}
     assert attention.prefill_attention(cfg, 4096, 4096) == {
-        "prefill_attention": "reference", "attention_blocks": "16/16"}
+        "prefill_attention": "reference", "attention_blocks": "16/16",
+        **cache}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert attention.prefill_attention(cfg, 4096, 4096) == {
-        "prefill_attention": "pallas", "attention_blocks": "36/64"}
+        "prefill_attention": "pallas", "attention_blocks": "36/64", **cache}
+
+
+def test_latent_prefill_attention_span_attributes(monkeypatch):
+    from repro.configs.deepseek_v2_lite import CONFIG
+    from repro.models import attention
+
+    # DeepSeek-V2-Lite at 8192 tokens: 27 latent caches of 512 + 64 bf16
+    cache = {"attention_kind": "mla", "cache_bytes_per_token": 31104}
+    assert attention.prefill_attention(CONFIG, 8192, None) == {
+        "prefill_attention": "reference", "attention_blocks": "64/64",
+        **cache}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention.prefill_attention(CONFIG, 8192, None) == {
+        "prefill_attention": "pallas", "attention_blocks": "136/256",
+        **cache}
 
 
 def _pallas_calls(fn, *args):

@@ -115,3 +115,75 @@ def test_router_warm_reuse(store):
     _, rec2 = r.invoke("g")
     assert rec1.cold and not rec2.cold
     assert rec2.latency < rec1.latency
+
+
+def test_engine_decodes_past_its_prompt_without_a_window():
+    """A model without a window keeps every decoded token's keys: the
+    engine's cache has room for the prompt and its decode steps, so its
+    tokens and last logits are the full forward pass's."""
+    import jax.numpy as jnp
+
+    from repro.models import lm
+
+    e = InferenceEngine("granite-3-2b", smoke=True, max_seq=16, batch=1,
+                        decode_steps=6)
+    e.cold_start()
+    assert e.bundle.window is None and e.cache_len == 22
+    prompt = np.random.default_rng(5).integers(
+        0, e.bundle.cfg.vocab_size, (1, 16)).astype(np.int32)
+    out, stats = e.serve(prompt, decode_steps=6)
+    seq = jnp.asarray(np.concatenate([prompt, out], axis=1))
+    want = np.asarray(lm.lm_forward(e.params, e.bundle.cfg,
+                                    {"tokens": seq})[0][0])
+    np.testing.assert_array_equal(out[0], want[15:21].argmax(-1))
+    np.testing.assert_allclose(stats.logits[0], want[21], atol=2e-4,
+                               rtol=2e-4)
+
+
+def test_danube_cache_shapes_unchanged_at_a_full_window():
+    """h2o-danube-1.8b at a 4096-token prompt, the width of its window,
+    keeps its 4096-slot ring after prefill and in decode, with room for
+    16 decoded tokens asked for."""
+    import jax
+
+    from repro.config import ModelConfig
+    from repro.models import registry
+
+    cfg = ModelConfig(name="danube", family="dense", source="-",
+                      num_layers=24, d_model=2560, num_heads=32,
+                      num_kv_heads=8, head_dim=80, d_ff=6912,
+                      vocab_size=32000, sliding_window=4096)
+    bundle = registry.build(cfg, max_seq=4096 + 16)
+    params = bundle.params_spec()
+    tokens = jax.ShapeDtypeStruct((1, 4096), np.int32)
+    caches = jax.eval_shape(lambda p, t: bundle.prefill(p, {"tokens": t})[1],
+                            params, tokens)
+    ring = (24, 1, 4096, 8, 80)
+    assert [a.shape for a in jax.tree.leaves(caches)] == [ring, ring]
+    assert [a.shape for a in jax.tree.leaves(
+        bundle.decode_caches_spec(1))] == [ring, ring]
+
+
+def test_latent_attention_expert_engine_reports_its_layout():
+    """DeepSeek-V2-Lite at the smoke size: ``engine.start.compile`` names
+    the latent cache and the experts held, and each prefill its routing."""
+    from repro import spans
+
+    e = InferenceEngine("deepseek-v2-lite", smoke=True, max_seq=16, batch=1,
+                        decode_steps=4)
+    spans.reset()
+    e.cold_start()
+    e.serve(np.ones((1, 16), np.int32), decode_steps=4)
+    (compile_,) = [s for s in spans.records()
+                   if s.name == "engine.start.compile"]
+    (prefill,) = [s for s in spans.records()
+                  if s.name == "engine.prefill_run"]
+    cfg = e.bundle.cfg                # 2 layers, latent 64 + rotated 32, f32
+    assert compile_.attrs["attention_kind"] == "mla"
+    assert compile_.attrs["cache_bytes_per_token"] == 2 * (64 + 32) * 4
+    assert compile_.attrs["experts_held"] == "1/4"
+    assert prefill.attrs["moe_dropped"] == 0
+    # one expert layer: 16 tokens, top-2 of 4 experts, 1 held
+    assert 0 <= prefill.attrs["moe_max_expert_tokens"] \
+        == prefill.attrs["moe_pairs_held"] <= 16
+    assert cfg.moe.top_k == 2 and cfg.moe.first_dense == 1

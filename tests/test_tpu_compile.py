@@ -91,6 +91,28 @@ def _batch_dense64_shapes():
     return len(cells), {k: getattr(t, k).shape[1:] for k in names}
 
 
+def test_deepseek_v2_lite_serving_fits_one_chip(one_chip):
+    """The engine's prefill and decode_step of DeepSeek-V2-Lite's one-chip
+    expert share (16 of 64 experts) at an 8192-token prompt and 32 decoded
+    tokens: latent attention, the dropless grouped expert matmuls."""
+    from repro.models import registry
+
+    bundle = registry.build_arch("deepseek-v2-lite", max_seq=8192 + 32)
+    params = _on(one_chip, bundle.params_spec())
+    batch = {"tokens": _spec(one_chip, (1, 8192), jnp.int32)}
+    prefill = jax.jit(bundle.prefill).lower(params, batch).compile()
+    caches = _on(one_chip, jax.eval_shape(
+        lambda p, b: bundle.prefill(p, b)[1], params, batch))
+    assert jax.tree.leaves(caches)[0].shape[2] == 8192 + 32
+    decode = jax.jit(bundle.decode_step).lower(
+        params, caches, _spec(one_chip, (1,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    for compiled in (prefill, decode):
+        args = compiled.memory_analysis().argument_size_in_bytes
+        assert args > 9.8e9                  # 9.82 GB of bf16 params
+        assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
 def test_batchsim_ref_scan_compiles_at_batch_dense64(one_chip):
     from repro.core import batchsim
 
@@ -126,6 +148,16 @@ def _flash_danube():
              ((1, 4096, 8, 80), bf)])
 
 
+def _flash_mla():
+    from repro.kernels.flash_attention import flash_attention_pallas
+
+    bf = jnp.bfloat16     # DeepSeek-V2-Lite's expanded prefill: 16 heads,
+    return (lambda q, k, v: flash_attention_pallas(   # qk 192, v 128
+        q, k, v, causal=True, scale=0.114721, interpret=False),
+            [((1, 8192, 16, 192), bf), ((1, 8192, 16, 192), bf),
+             ((1, 8192, 16, 128), bf)])
+
+
 def _decode():
     from repro.kernels.decode_attention import decode_attention_pallas
 
@@ -158,9 +190,10 @@ def _cluster():
 
 
 @pytest.mark.parametrize("kernel", [_flash, _flash_danube, _decode, _ssm,
-                                    _cluster],
+                                    _cluster, _flash_mla],
                          ids=["flash_attention", "flash_attention_danube",
-                              "decode_attention", "ssm_scan", "cluster_step"])
+                              "decode_attention", "ssm_scan", "cluster_step",
+                              "flash_attention_mla"])
 def test_pallas_kernel_compiles_natively(one_chip, kernel):
     fn, arg_shapes = kernel()
     args = [_spec(one_chip, shape, dtype) for shape, dtype in arg_shapes]
