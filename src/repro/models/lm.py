@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import sharding
-from repro.models import layers, transformer
+from repro.models import layers, moe, transformer
 
 
 def init_lm(rng, cfg, *, max_seq: int):
@@ -141,12 +141,16 @@ def _format_caches(cfg, raw_caches, *, seq_len: int, max_seq: int, window):
 
 
 def lm_decode_step(p, cfg, caches, token, pos, *, window=None):
-    """token: (B,) int32; pos: scalar int32.  Returns (logits (B, V), caches)."""
+    """token: (B,) int32; pos: scalar int32.  Returns (logits (B, V),
+    caches); a model with experts reports the step's routing counts to
+    an open ``moe.counting`` block."""
     with jax.named_scope("embed"):
         x = jnp.take(p["embed"], token, axis=0).astype(jnp.dtype(cfg.dtype))
-    x, caches = transformer.stack_decode(p["blocks"], x, cfg, pos=pos,
-                                         lead=p.get("lead", ()),
-                                         window=window, caches=caches)
+    x, caches, counts = transformer.stack_decode(
+        p["blocks"], x, cfg, pos=pos, lead=p.get("lead", ()), window=window,
+        caches=caches)
+    if cfg.moe is not None:
+        moe.report(counts)
     x = _final_norm(p, cfg, x)
     logits = _unembed(p, cfg, x[:, None, :])[:, 0, :]
     return logits, caches

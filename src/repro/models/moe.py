@@ -7,9 +7,12 @@ experts' rows go through one grouped matmul per projection
 buffer has a row for every pair, so no pair is ever dropped, however skewed
 the routing: there is no capacity.  Pairs routed to experts this device
 does not hold sort last, past every group, and add nothing.  A call with no
-more pairs than held experts (a decode step) takes every held expert for
-each token instead, weighted by its gates: the same weights are read, and
-no grouped matmul is expanded for a handful of rows.
+more pairs than held experts (a decode step) reads only the held experts
+its pairs chose instead, and passes each token through each of them,
+weighted by its gates (0 where it did not choose the expert): on a TPU the
+Pallas kernel ``kernels/moe_decode.py`` does, reading the decode scan's
+expert stacks in place; elsewhere every held expert is read, gated, which
+is the kernel's reference (``expert_path``).
 
 A layer holds experts 0 .. ``MoEConfig.experts_held`` - 1 (all of them by
 default): the share chip 0 of an expert-parallel deployment computes.  On
@@ -21,14 +24,20 @@ float32 product is one bfloat16 pass, which flips near-tie top-k choices.
 
 A dense FFN that every token passes beside the experts (``shared_ff``:
 Arctic's dense residual, DeepSeek's shared experts) is added once.  The
-layer returns the load-balance auxiliary loss and three counts of its
-routing: pairs routed to held experts, the heaviest held expert's pairs,
-and the held pairs the dispatch left uncomputed (dropped: 0 unless it
-is at fault).
+layer returns the load-balance auxiliary loss and counts of its routing:
+pairs routed to held experts, the heaviest held expert's pairs, the held
+pairs the dispatch left uncomputed (dropped: 0 unless it is at fault),
+the held experts whose weights it read, and 1 for the layer itself, so
+that sums over layers and steps can be divided by it.
+
+``lm_decode_step`` returns logits and caches alone; a decode step's counts
+reach a caller that traces it under ``counting`` (``counted_decode``).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import contextlib
+import contextvars
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +45,10 @@ import jax.numpy as jnp
 from repro import sharding
 from repro.models import layers
 
-DISPATCH = "sorted ragged_dot; dense over held at decode"   # moe_dispatch
+# the expert paths (``expert_path``), as ``moe_dispatch`` names them
+DISPATCH = {"sorted": "sorted ragged_dot", "dense": "dense over held",
+            "pallas": "pallas over chosen held"}
+EXPERT_WEIGHTS = ("wi", "wg", "wo")
 
 
 def init_moe(rng, cfg):
@@ -63,17 +75,99 @@ def init_moe(rng, cfg):
     return p
 
 
-def start_attrs(cfg) -> Dict[str, str]:
-    """Span attributes of ``engine.start.compile`` for an MoE model."""
+def expert_path(pairs: int, held: int) -> str:
+    """The expert pass of a call with ``pairs`` (token, expert) pairs over
+    ``held`` experts: ``"sorted"``, the grouped matmuls, when it has more
+    pairs than held experts; for no more (a decode step) ``"pallas"``, the
+    kernel that reads only the chosen held experts, on a TPU outside a
+    sharding context (one device), and ``"dense"``, every held expert
+    gated, elsewhere."""
+    if pairs > held:
+        return "sorted"
+    if (jax.default_backend() == "tpu"
+            and sharding.current_rules_and_mesh() is None):
+        return "pallas"
+    return "dense"
+
+
+def start_attrs(cfg, batch: int) -> Dict[str, str]:
+    """Span attributes of ``engine.start.compile`` for an MoE model whose
+    decode steps take ``batch`` tokens."""
     m = cfg.moe
+    decode = expert_path(batch * m.top_k, m.held)
     return {"experts_held": f"{m.held}/{m.num_experts}",
-            "moe_dispatch": DISPATCH}
+            "moe_dispatch": f"{DISPATCH['sorted']}; {DISPATCH[decode]} "
+                            f"at decode"}
 
 
 def zero_counts() -> Dict[str, jax.Array]:
-    return {"moe_pairs_held": jnp.zeros((), jnp.int32),
-            "moe_max_expert_tokens": jnp.zeros((), jnp.int32),
-            "moe_dropped": jnp.zeros((), jnp.int32)}
+    return {k: jnp.zeros((), jnp.int32)
+            for k in ("moe_pairs_held", "moe_max_expert_tokens",
+                      "moe_dropped", "moe_experts_read", "moe_layers")}
+
+
+# the innermost open ``counting`` block's list, per thread and context
+_counting: contextvars.ContextVar[Optional[list]] = contextvars.ContextVar(
+    "moe_counting", default=None)
+
+
+@contextlib.contextmanager
+def counting():
+    """Collects, in the list it yields, the routing counts that decode
+    steps traced inside the block ``report``."""
+    got: list = []
+    token = _counting.set(got)
+    try:
+        yield got
+    finally:
+        _counting.reset(token)
+
+
+def report(counts: Dict[str, jax.Array]) -> None:
+    """A decode step's counts, summed over its layers, for the innermost
+    open ``counting`` block (none open: dropped)."""
+    got = _counting.get()
+    if got is not None:
+        got.append(counts)
+
+
+def counted_decode(decode_step):
+    """``decode_step`` (p, caches, token, pos) -> (logits, caches) as one
+    program that also adds the step's routing counts to a running sum:
+    (p, caches, token, pos, routed) -> (logits, caches, routed).  For a
+    model without experts ``routed`` is ``{}`` and stays so."""
+    def decode_step_counted(p, caches, token, pos, routed):
+        with counting() as steps:
+            logits, caches = decode_step(p, caches, token, pos)
+        for n in steps:
+            routed = jax.tree.map(jnp.add, routed, n)
+        return logits, caches, routed
+
+    decode_step_counted.__name__ = decode_step.__name__
+    return decode_step_counted
+
+
+def split_stacks(p, meta, cfg, tokens: int):
+    """A decode scan position's params without the held experts' weights,
+    and those weights whole (``None`` where the position keeps them), for
+    an MoE layer whose ``tokens``-token steps take the kernel: it reads
+    the stacked ``[n_rep, held, ...]`` weights in place, given the layer's
+    index, so that no layer's slice of them is copied each step."""
+    m = cfg.moe
+    if meta["ffn"] != "moe" or expert_path(tokens * m.top_k, m.held) \
+            != "pallas":
+        return p, None
+    inner = {k: v for k, v in p["moe"].items() if k not in EXPERT_WEIGHTS}
+    whole = {k: v for k, v in p["moe"].items() if k in EXPERT_WEIGHTS}
+    return {**p, "moe": inner}, whole
+
+
+def join_stacks(p, whole, layer):
+    """``p`` with the weights ``split_stacks`` took whole, and the index
+    of ``p``'s layer in them."""
+    if whole is None:
+        return p
+    return {**p, "moe": {**p["moe"], **whole, "layer": layer}}
 
 
 def _route(x, router, m):
@@ -97,11 +191,13 @@ def _ffn(h_in, p, mm):
 def _experts(x, p, cfg, *, first, held):
     """The part of experts ``first .. first + held - 1`` for one token set.
 
-    x: (t, d) -> (y (t, d) f32, aux loss, counts).  With no more pairs
-    than held experts (a decode step) every held expert's weights are read
-    once either way, so each token goes through all of them, weighted by
-    its gates (0 where not routed); otherwise the pairs are sorted by
-    expert through the grouped matmuls, each pair computed once."""
+    x: (t, d) -> (y (t, d) f32, aux loss, counts).  The pairs are sorted
+    by expert and each computed once, through the grouped matmuls or, with
+    no more pairs than held experts (a decode step) on a TPU, the kernel
+    that reads only the chosen held experts (``expert_path``); there ``p``
+    may hold the weights as whole stacks with the layer's index
+    (``join_stacks``).  Elsewhere such a call takes every held expert for
+    each token, weighted by its gates (0 where not routed)."""
     m = cfg.moe
     t, d = x.shape
     k, e = m.top_k, m.num_experts
@@ -113,9 +209,11 @@ def _experts(x, p, cfg, *, first, held):
     sizes = jnp.bincount(group, length=held + 1)[:held]
     w = jnp.where(mine, top_w.reshape(t * k), 0.0)
     tok = jnp.arange(t * k) // k
-    if t * k <= held:
+    path = expert_path(t * k, held)
+    if path == "dense":
         gate = jnp.zeros((t, held + 1), jnp.float32).at[tok, group].add(w)
         gated = jnp.zeros((t, held + 1), jnp.int32).at[tok, group].add(1)
+        read = held
         computed = gated[:, :held].sum()
         h = _ffn(x, p, lambda a, b: jnp.einsum("td,edf->tef", a, b))
         out = jnp.einsum("tef,efd->ted", h, p["wo"],
@@ -123,13 +221,27 @@ def _experts(x, p, cfg, *, first, held):
         y = jnp.einsum("ted,te->td", out, gate[:, :held])
     else:
         order = jnp.argsort(group, stable=True)
-        rows = x[tok[order]]                       # (t*k, d), by expert
+        n = sizes.sum()                   # held pairs: the first n rows
+        if path == "pallas":
+            from repro.kernels.moe_decode import held_experts_pallas
 
-        def mm(a, b):
-            return jax.lax.ragged_dot(a, b, sizes)
+            # past the held pairs the last held expert again: no DMA
+            srt = group[order]
+            ids = jnp.minimum(srt, jnp.minimum(srt[jnp.maximum(n - 1, 0)],
+                                               held - 1))
+            stacks = {name: p[name] if "layer" in p else p[name][None]
+                      for name in EXPERT_WEIGHTS if name in p}
+            out = held_experts_pallas(x, tok[order], ids, n,
+                                      p.get("layer", 0), stacks["wi"],
+                                      stacks["wo"], stacks.get("wg"))
+        else:
+            def mm(a, b):
+                return jax.lax.ragged_dot(a, b, sizes)
 
-        out = mm(_ffn(rows, p, mm), p["wo"])
-        live = jnp.arange(t * k) < sizes.sum()    # rows past the groups: none
+            rows = x[tok[order]]                  # (t*k, d), by expert
+            out = mm(_ffn(rows, p, mm), p["wo"])
+        read = (sizes > 0).sum()
+        live = jnp.arange(t * k) < n              # rows past the groups: none
         computed = jnp.sum(live & mine[order])
         out = jnp.where(live[:, None], out.astype(jnp.float32), 0.0)
         y = jnp.zeros((t, d), jnp.float32).at[tok[order]].add(
@@ -140,7 +252,9 @@ def _experts(x, p, cfg, *, first, held):
     aux = e * jnp.sum(f * probs.mean(axis=0))
     counts = {"moe_pairs_held": sizes.sum().astype(jnp.int32),
               "moe_max_expert_tokens": sizes.max().astype(jnp.int32),
-              "moe_dropped": (mine.sum() - computed).astype(jnp.int32)}
+              "moe_dropped": (mine.sum() - computed).astype(jnp.int32),
+              "moe_experts_read": jnp.asarray(read, jnp.int32),
+              "moe_layers": jnp.ones((), jnp.int32)}
     return y, aux, counts
 
 
@@ -196,12 +310,10 @@ def _moe_ffn_ep(p, x, cfg, rules, mesh):
             y = y + layers.mlp_apply(shared_local, xl, cfg.act).astype(
                 jnp.float32)
         y = jax.lax.psum(y, model_ax)
-        counts = {"moe_pairs_held": jax.lax.psum(counts["moe_pairs_held"],
-                                                 model_ax),
-                  "moe_max_expert_tokens": jax.lax.pmax(
-                      counts["moe_max_expert_tokens"], model_ax),
-                  "moe_dropped": jax.lax.psum(counts["moe_dropped"],
-                                              model_ax)}
+        counts = {k: (jax.lax.pmax if k in ("moe_max_expert_tokens",
+                                            "moe_layers")
+                      else jax.lax.psum)(v, model_ax)
+                  for k, v in counts.items()}
         return y.astype(xl.dtype), aux, counts
 
     in_specs = (spec_parts, shared_spec, P(batch_ax, seq_ax, None))

@@ -184,7 +184,7 @@ def _mixer_full(p, h, cfg, kind, q_pos, window, states, train):
 
 
 def _block_decode(p, x, cfg, meta, pos, window, cache):
-    """One-token block.  x: (B, d).  Returns (x, new_cache)."""
+    """One-token block.  x: (B, d).  Returns (x, new_cache, counts)."""
     with jax.named_scope("norm"):
         h = layers.norm_apply(p["norm1"], x, cfg.norm)
     kind = meta["kind"]
@@ -192,8 +192,8 @@ def _block_decode(p, x, cfg, meta, pos, window, cache):
         y, cache = _mixer_decode(p, h, cfg, kind, pos, window, cache)
     x = x + y
     x3 = x[:, None, :]
-    x3, _, _ = _apply_ffn(p, x3, cfg)
-    return x3[:, 0, :], cache
+    x3, _, counts = _apply_ffn(p, x3, cfg)
+    return x3[:, 0, :], cache, counts
 
 
 def _mixer_decode(p, h, cfg, kind, pos, window, cache):
@@ -268,41 +268,58 @@ def stack_full(stack_params, x, cfg, *, q_pos, window=None, train=False,
 
 
 def _scan_decode(stack_params, metas, n_rep, x, cfg, pos, window, caches):
-    def period_fn(x, xs):
-        period_params, period_caches = xs
+    """One stacked group of layers for one token.  Returns (x, new caches,
+    counts).  Held experts that the decode kernel reads in place stay out
+    of the scanned params (``moe.split_stacks``): the scan passes them
+    whole, with the layer's index."""
+    split = [moe.split_stacks(p, meta, cfg, x.shape[0])
+             for p, meta in zip(stack_params, metas)]
+    scanned = tuple(p for p, _ in split)
+    whole = [w for _, w in split]
+
+    def period_fn(carry, xs):
+        x, counts = carry
+        period_params, period_caches, layer = xs
         new = []
         for i, meta in enumerate(metas):
-            x, c = _block_decode(period_params[i], x, cfg, meta, pos, window,
-                                 period_caches[i])
+            p = moe.join_stacks(period_params[i], whole[i], layer)
+            x, c, n = _block_decode(p, x, cfg, meta, pos, window,
+                                    period_caches[i])
+            counts = jax.tree.map(jnp.add, counts, n)
             new.append(c)
-        return x, tuple(new)
+        return (x, counts), tuple(new)
 
+    carry = (x, moe.zero_counts())
     if cfg.unroll_layers:
         outs = []
         for i in range(n_rep):
-            xs_i = jax.tree.map(lambda a: a[i],
-                                (tuple(stack_params), tuple(caches)))
-            x, new_i = period_fn(x, xs_i)
+            xs_i = jax.tree.map(lambda a: a[i], (scanned, tuple(caches)))
+            carry, new_i = period_fn(carry, (*xs_i, i))
             outs.append(new_i)
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        return x, list(new_caches)
-    x, new_caches = jax.lax.scan(period_fn, x, (tuple(stack_params), tuple(caches)))
-    return x, list(new_caches)
+    else:
+        layers_ = jnp.arange(n_rep) if any(w is not None for w in whole) \
+            else None
+        carry, new_caches = jax.lax.scan(period_fn, carry,
+                                         (scanned, tuple(caches), layers_))
+    x, counts = carry
+    return x, list(new_caches), counts
 
 
 def stack_decode(stack_params, x, cfg, *, pos, window=None, caches=None,
                  lead=()):
-    """x: (B, d) one token -> (x, new_caches); ``lead`` as in
-    ``stack_full``."""
+    """x: (B, d) one token -> (x, new_caches, routing counts); ``lead``
+    and the counts as in ``stack_full``."""
     n_rep = (cfg.num_layers - lead_len(cfg)) // period_len(cfg)
-    new = []
+    new, counts = [], moe.zero_counts()
     if lead:
-        x, new = _scan_decode(lead, _lead_meta(cfg), lead_len(cfg), x, cfg,
-                              pos, window, caches[:len(lead)])
+        x, new, counts = _scan_decode(lead, _lead_meta(cfg), lead_len(cfg),
+                                      x, cfg, pos, window,
+                                      caches[:len(lead)])
         caches = caches[len(lead):]
-    x, rest = _scan_decode(stack_params, _block_meta(cfg), n_rep, x, cfg,
-                           pos, window, caches)
-    return x, new + rest
+    x, rest, n = _scan_decode(stack_params, _block_meta(cfg), n_rep, x, cfg,
+                              pos, window, caches)
+    return x, new + rest, jax.tree.map(jnp.add, counts, n)
 
 
 def init_decode_caches(cfg, batch: int, max_seq: int, *, window=None):

@@ -203,7 +203,7 @@ class InferenceEngine:
                 attrs.update(attention.prefill_attention(
                     cfg, self.max_seq, self.bundle.window))
             if cfg.moe is not None:
-                attrs.update(moe.start_attrs(cfg))
+                attrs.update(moe.start_attrs(cfg, self.batch))
             with spans.span("engine.start.compile",
                             executable_hit=exe is not None, **attrs) as code:
                 with compile_cache.counting() as cache:
@@ -229,13 +229,20 @@ class InferenceEngine:
             params_spec, bspec).compile()
         caches_spec = jax.eval_shape(
             lambda p, b: self.bundle.prefill(p, b)[1], params_spec, bspec)
-        self._decode_c = jax.jit(self.bundle.decode_step).lower(
+        self._decode_c = jax.jit(moe.counted_decode(
+            self.bundle.decode_step)).lower(
             params_spec, caches_spec,
             jax.ShapeDtypeStruct((self.batch,), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32)).compile()
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.eval_shape(self._zero_routed)).compile()
         if self.store is not None:
             self.store.put_executable(
                 self.key, (self._prefill_c, self._decode_c))
+
+    def _zero_routed(self):
+        """The decode program's running routing counts, before its first
+        step (``moe.counted_decode``): none for a model without experts."""
+        return {} if self.bundle.cfg.moe is None else moe.zero_counts()
 
     def shutdown(self):
         """Scale to zero: drop device state (keep nothing warm)."""
@@ -270,17 +277,22 @@ class InferenceEngine:
                         {k: int(v) for k, v in jax.device_get(counts).items()})
             out = []
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            routed = self._zero_routed()
             with spans.span("engine.decode") as decode:
                 p = jnp.asarray(tokens.shape[1], jnp.int32)
                 for i in range(decode_steps):
                     with spans.span("engine.token_fetch"):
                         out.append(np.asarray(tok))
                     with spans.span("engine.step_dispatch"):
-                        logits, caches = self._decode_c(self.params, caches,
-                                                        tok, p + i)
+                        logits, caches, routed = self._decode_c(
+                            self.params, caches, tok, p + i, routed)
                         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 with spans.span("engine.final_wait"):
                     jax.block_until_ready(tok)
+                if routed:      # the steps' routing, over the MoE layers
+                    decode.attrs.update(
+                        {k.replace("moe_", "moe_decode_", 1): int(v)
+                         for k, v in jax.device_get(routed).items()})
             with spans.span("engine.logits_fetch"):
                 stats.logits = np.asarray(logits)
             generated = np.stack(out, axis=1)

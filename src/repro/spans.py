@@ -33,8 +33,11 @@ The names, by layer (``NAMES``):
       engine.upload               the prompt to the device
       engine.prefill_run          prefill dispatched and waited for
                                   (moe_pairs_held, moe_max_expert_tokens,
-                                  moe_dropped)
-      engine.decode               the decode loop (ServeStats.decode_s)
+                                  moe_dropped, moe_experts_read,
+                                  moe_layers)
+      engine.decode               the decode loop (ServeStats.decode_s;
+                                  the same counters over its steps,
+                                  moe_decode_*)
         engine.token_fetch        the previous token to the host, per step
         engine.step_dispatch      index add, decode program, argmax, per step
         engine.final_wait         the last token waited for

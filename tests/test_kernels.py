@@ -250,3 +250,183 @@ def test_auto_attention_path_by_platform_and_gradient(monkeypatch):
     assert _pallas_calls(jax.grad(loss), params) == 0
     grads = jax.grad(loss)(params)
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
+
+
+# --------------------------------------------------------------------------- #
+# the decode expert kernel (kernels/moe_decode.py) against the dense pass
+# --------------------------------------------------------------------------- #
+
+
+def _moe_layer_cfg(experts, held, top_k, d, ff, act="swiglu",
+                   dtype="float32"):
+    from repro.config import MoEConfig, ModelConfig
+
+    return ModelConfig(name="t", family="moe", source="t", num_layers=1,
+                       d_model=d, num_heads=1, vocab_size=64, act=act,
+                       dtype=dtype, param_dtype=dtype,
+                       moe=MoEConfig(num_experts=experts, top_k=top_k,
+                                     expert_ff=ff, norm_topk=False,
+                                     experts_held=held))
+
+
+def _on_the_kernel(monkeypatch):
+    """``moe.expert_path`` as on a TPU: a call with no more pairs than
+    held experts takes the kernel (interpreted here)."""
+    from repro.models import moe
+
+    path = moe.expert_path
+    monkeypatch.setattr(moe, "expert_path",
+                        lambda pairs, held: "pallas"
+                        if path(pairs, held) == "dense" else path(pairs, held))
+
+
+MOE_DECODE_CASES = {
+    # name: (experts, held, top_k, d, ff, act, dtype, chosen): ``chosen``
+    # is each token's experts by rank, None a random router
+    "none_held": (8, 4, 2, 64, 128, "swiglu", "float32", [[6, 5]]),
+    "one_held": (8, 4, 2, 64, 128, "swiglu", "float32", [[2, 6]]),
+    "all_held": (8, 4, 2, 64, 128, "swiglu", "float32", [[3, 1]]),
+    "same_expert": (8, 6, 2, 64, 128, "swiglu", "float32", [[1, 3], [1, 2]]),
+    "pairs_equal_held": (8, 4, 2, 64, 128, "swiglu", "float32",
+                         [[0, 1], [3, 2]]),
+    "gelu": (8, 4, 2, 64, 256, "gelu", "float32", [[1, 7]]),
+    # DeepSeek-V2-Lite's ratios at smoke widths: top-6 of 64, 16 held,
+    # expert ff / d_model = 1408 / 2048
+    "deepseek_ratio": (64, 16, 6, 256, 176, "swiglu", "bfloat16", None),
+}
+
+
+@pytest.mark.parametrize("name", MOE_DECODE_CASES)
+def test_moe_decode_kernel_matches_dense_pass(name, monkeypatch):
+    """The kernel's path of ``moe._experts`` (chosen held experts only)
+    gives the dense gated pass's output and counts, save the experts
+    read: those the pairs chose, not every held one."""
+    from repro.models import moe
+
+    experts, held, k, d, ff, act, dtype, chosen = MOE_DECODE_CASES[name]
+    cfg = _moe_layer_cfg(experts, held, k, d, ff, act, dtype)
+    p = moe.init_moe(jax.random.key(7), cfg)
+    t = len(chosen) if chosen else 1
+    x = RNG.normal(size=(t, d)).astype(np.float32)
+    if chosen:
+        # router weights under which each token ranks its chosen experts
+        # first, in order, and the rest far below
+        scores = np.full((t, experts), -8.0, np.float32)
+        for i, row in enumerate(chosen):
+            scores[i, row] = 4.0 - np.arange(len(row))
+        p["router"] = jnp.asarray(np.linalg.pinv(x) @ scores)
+    x = jnp.asarray(x, dtype)
+    want, _, dense = moe._experts(x, p, cfg, first=0, held=held)
+    _on_the_kernel(monkeypatch)
+    got, _, kernel = moe._experts(x, p, cfg, first=0, held=held)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(jnp.dtype(dtype)))
+    routed = jax.lax.top_k(jnp.asarray(x, jnp.float32) @ p["router"], k)[1]
+    read = len(set(np.asarray(routed).ravel().tolist()) & set(range(held)))
+    if chosen:
+        assert np.asarray(routed).tolist() == chosen
+    assert int(dense["moe_experts_read"]) == held
+    assert int(kernel["moe_experts_read"]) == read
+    for key in ("moe_pairs_held", "moe_max_expert_tokens", "moe_dropped"):
+        assert int(kernel[key]) == int(dense[key])
+    assert int(kernel["moe_dropped"]) == 0
+
+
+@pytest.mark.parametrize("block_ff", [None, 128])
+def test_moe_decode_kernel_reads_each_slots_expert(block_ff):
+    """The kernel alone, whole ff or tiled: slot s holds its token through
+    layer ``layer``'s expert ``ids[s]``, two tokens of one expert read it
+    in consecutive slots, and slots past ``n`` are zero."""
+    from repro.kernels.moe_decode import held_experts_pallas
+
+    reps, held, d, ff, t = 3, 5, 64, 256, 2
+    wi, wg, wo = (jnp.asarray(RNG.normal(size=s) * s[-2] ** -0.5, jnp.float32)
+                  for s in ((reps, held, d, ff), (reps, held, d, ff),
+                            (reps, held, ff, d)))
+    x = jnp.asarray(RNG.normal(size=(t, d)), jnp.float32)
+    tok = jnp.asarray([0, 1, 0, 0], jnp.int32)
+    ids = jnp.asarray([0, 3, 3, 3], jnp.int32)
+    got = held_experts_pallas(x, tok, ids, 3, 1, wi, wo, wg,
+                              block_ff=block_ff)
+    for s in range(3):
+        xs, e = x[tok[s]], ids[s]
+        h = jax.nn.silu(xs @ wi[1, e]) * (xs @ wg[1, e])
+        np.testing.assert_allclose(np.asarray(got[s]), np.asarray(h @ wo[1, e]),
+                                   atol=1e-4, rtol=1e-4)
+    assert not np.asarray(got[3]).any()
+
+
+def _small_deepseek(unroll: bool):
+    """DeepSeek-V2-Lite's smoke shape (latent attention, a leading dense
+    layer, shared experts), in bfloat16, with 3 layers and an expert share
+    in which a decode step's pairs are no more than the held experts:
+    top-3 of 16, 8 held."""
+    import dataclasses
+
+    from repro.configs.deepseek_v2_lite import SMOKE
+
+    return dataclasses.replace(
+        SMOKE, num_layers=3, dtype="bfloat16", param_dtype="bfloat16",
+        unroll_layers=unroll,
+        moe=dataclasses.replace(SMOKE.moe, num_experts=16, top_k=3,
+                                experts_held=8))
+
+
+@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unrolled"])
+def test_moe_decode_kernel_in_a_deepseek_decode_step(unroll, monkeypatch):
+    """A decode step of a small DeepSeek-shaped model through its layer
+    scan (or the unrolled loop): the kernel's logits are the dense pass's
+    to bfloat16 tolerance, and no held pair is dropped."""
+    from repro.models import moe
+
+    cfg = _small_deepseek(unroll)
+    assert cfg.moe.first_dense == 1 and cfg.moe.shared_ff
+    bundle = registry.build(cfg, max_seq=24)
+    params = bundle.init(jax.random.key(3))
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (1, 17)), jnp.int32)
+    _, caches, pos, _ = bundle.prefill(params, {"tokens": tokens[:, :16]})
+    step = moe.counted_decode(bundle.decode_step)
+    args = (params, caches, tokens[:, 16], jnp.asarray(pos, jnp.int32),
+            moe.zero_counts())
+    want, _, dense = jax.jit(step)(*args)
+    assert _pallas_calls(step, *args) == 0
+    _on_the_kernel(monkeypatch)
+    got, _, kernel = jax.jit(moe.counted_decode(bundle.decode_step))(*args)
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2 * scale, rtol=0)
+    layers = cfg.num_layers - cfg.moe.first_dense
+    assert int(kernel["moe_layers"]) == int(dense["moe_layers"]) == layers
+    assert int(kernel["moe_dropped"]) == int(dense["moe_dropped"]) == 0
+    assert int(dense["moe_experts_read"]) == 8 * layers
+    assert int(kernel["moe_pairs_held"]) == int(dense["moe_pairs_held"]) > 0
+    assert 0 < int(kernel["moe_experts_read"]) <= int(
+        kernel["moe_pairs_held"])
+    assert _pallas_calls(moe.counted_decode(bundle.decode_step), *args) > 0
+
+
+def test_expert_path_by_platform_and_pairs(monkeypatch):
+    """The kernel on a single TPU for no more pairs than held experts;
+    the dense pass on the CPU or under a sharding context; the grouped
+    matmuls for more pairs.  ``moe_dispatch`` names the decode path."""
+    from repro import sharding
+    from repro.configs.deepseek_v2_lite import CONFIG
+    from repro.models import moe
+
+    assert [moe.expert_path(p, 16) for p in (6, 16, 17)] == [
+        "dense", "dense", "sorted"]
+    assert moe.start_attrs(CONFIG, 1)["moe_dispatch"] == \
+        "sorted ragged_dot; dense over held at decode"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert [moe.expert_path(p, 16) for p in (6, 16, 17)] == [
+        "pallas", "pallas", "sorted"]
+    assert moe.start_attrs(CONFIG, 1) == {
+        "experts_held": "16/64",
+        "moe_dispatch": "sorted ragged_dot; pallas over chosen held at decode"}
+    assert moe.start_attrs(CONFIG, 3)["moe_dispatch"] == \
+        "sorted ragged_dot; sorted ragged_dot at decode"
+    monkeypatch.setattr(sharding, "current_rules_and_mesh",
+                        lambda: ({"expert": "model"}, None))
+    assert moe.expert_path(6, 16) == "dense"

@@ -178,12 +178,22 @@ def test_latent_attention_expert_engine_reports_its_layout():
                    if s.name == "engine.start.compile"]
     (prefill,) = [s for s in spans.records()
                   if s.name == "engine.prefill_run"]
+    (decode,) = [s for s in spans.records() if s.name == "engine.decode"]
     cfg = e.bundle.cfg                # 2 layers, latent 64 + rotated 32, f32
     assert compile_.attrs["attention_kind"] == "mla"
     assert compile_.attrs["cache_bytes_per_token"] == 2 * (64 + 32) * 4
     assert compile_.attrs["experts_held"] == "1/4"
+    # a decode step's 2 pairs are more than the 1 held expert
+    assert compile_.attrs["moe_dispatch"] == \
+        "sorted ragged_dot; sorted ragged_dot at decode"
     assert prefill.attrs["moe_dropped"] == 0
     # one expert layer: 16 tokens, top-2 of 4 experts, 1 held
     assert 0 <= prefill.attrs["moe_max_expert_tokens"] \
         == prefill.attrs["moe_pairs_held"] <= 16
+    assert prefill.attrs["moe_layers"] == 1
     assert cfg.moe.top_k == 2 and cfg.moe.first_dense == 1
+    # the 4 steps' routing, fetched once after the last
+    assert decode.attrs["moe_decode_layers"] == 4
+    assert decode.attrs["moe_decode_dropped"] == 0
+    assert 0 <= decode.attrs["moe_decode_experts_read"] \
+        <= decode.attrs["moe_decode_pairs_held"] <= 4

@@ -3,15 +3,17 @@
 The TPU compiler is installed even where no chip is attached: it compiles
 for a *described* v5e and refuses what the chip would refuse (misaligned
 Pallas blocks, primitives Mosaic cannot lower, programs that do not fit
-HBM).  These tests compile the serving path at granite-3-2b's published
-widths, the batch simulator's device program at ``batch_dense64`` shapes,
-and every Pallas kernel at real widths natively (``interpret=False``).
+HBM).  These tests compile the serving path at granite-3-2b's and
+DeepSeek-V2-Lite's published widths, the batch simulator's device program
+at ``batch_dense64`` shapes, and every Pallas kernel at real widths
+natively (``interpret=False``).
 
 Nothing runs, so these say nothing about results or times.  The topology
 is described inside a fixture, never at import: only one process may load
 the TPU runtime at a time, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,11 +93,13 @@ def _batch_dense64_shapes():
     return len(cells), {k: getattr(t, k).shape[1:] for k in names}
 
 
-def test_deepseek_v2_lite_serving_fits_one_chip(one_chip):
-    """The engine's prefill and decode_step of DeepSeek-V2-Lite's one-chip
-    expert share (16 of 64 experts) at an 8192-token prompt and 32 decoded
-    tokens: latent attention, the dropless grouped expert matmuls."""
-    from repro.models import registry
+def test_deepseek_v2_lite_serving_fits_one_chip(one_chip, monkeypatch):
+    """The engine's prefill and decode programs of DeepSeek-V2-Lite's
+    one-chip expert share (16 of 64 experts) at an 8192-token prompt and 32
+    decoded tokens: latent attention, the dropless grouped expert matmuls,
+    and decode's expert kernel, which reads the layer scan's expert stacks
+    in place: no program op makes a copy of one layer's 16 experts."""
+    from repro.models import moe, registry
 
     bundle = registry.build_arch("deepseek-v2-lite", max_seq=8192 + 32)
     params = _on(one_chip, bundle.params_spec())
@@ -104,13 +108,19 @@ def test_deepseek_v2_lite_serving_fits_one_chip(one_chip):
     caches = _on(one_chip, jax.eval_shape(
         lambda p, b: bundle.prefill(p, b)[1], params, batch))
     assert jax.tree.leaves(caches)[0].shape[2] == 8192 + 32
-    decode = jax.jit(bundle.decode_step).lower(
+    # the path a TPU takes (``moe.expert_path``), compiled natively
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    decode = jax.jit(moe.counted_decode(bundle.decode_step)).lower(
         params, caches, _spec(one_chip, (1,), jnp.int32),
-        _spec(one_chip, (), jnp.int32)).compile()
+        _spec(one_chip, (), jnp.int32),
+        _on(one_chip, jax.eval_shape(moe.zero_counts))).compile()
     for compiled in (prefill, decode):
         args = compiled.memory_analysis().argument_size_in_bytes
         assert args > 9.8e9                  # 9.82 GB of bf16 params
         assert _device_bytes(compiled) < V5E_HBM_BYTES
+    text = decode.as_text()
+    assert "moe_held_experts" in text
+    assert not re.search(r"= bf16\[16,(2048,1408|1408,2048)\]", text)
 
 
 def test_batchsim_ref_scan_compiles_at_batch_dense64(one_chip):
@@ -168,6 +178,18 @@ def _decode():
              ((1, 2048, 8, 64), bf), ((1, 2048), jnp.bool_)])
 
 
+def _moe_decode():
+    from repro.kernels.moe_decode import held_experts_pallas
+
+    bf = jnp.bfloat16     # DeepSeek-V2-Lite's decode: 26 MoE layers, 16
+    i32 = jnp.int32       # held experts of 2048 x 1408, top-6, one token
+    return (lambda x, tok, ids, n, layer, wi, wo, wg: held_experts_pallas(
+        x, tok, ids, n, layer, wi, wo, wg, interpret=False),
+            [((1, 2048), bf), ((6,), i32), ((6,), i32), ((), i32), ((), i32),
+             ((26, 16, 2048, 1408), bf), ((26, 16, 1408, 2048), bf),
+             ((26, 16, 2048, 1408), bf)])
+
+
 def _ssm():
     from repro.kernels.ssm_scan import ssm_scan_pallas
 
@@ -190,10 +212,10 @@ def _cluster():
 
 
 @pytest.mark.parametrize("kernel", [_flash, _flash_danube, _decode, _ssm,
-                                    _cluster, _flash_mla],
+                                    _cluster, _flash_mla, _moe_decode],
                          ids=["flash_attention", "flash_attention_danube",
                               "decode_attention", "ssm_scan", "cluster_step",
-                              "flash_attention_mla"])
+                              "flash_attention_mla", "moe_decode"])
 def test_pallas_kernel_compiles_natively(one_chip, kernel):
     fn, arg_shapes = kernel()
     args = [_spec(one_chip, shape, dtype) for shape, dtype in arg_shapes]
