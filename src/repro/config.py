@@ -143,8 +143,6 @@ class ModelConfig:
     dtype: str = "bfloat16"         # activation/compute dtype
     param_dtype: str = "bfloat16"   # parameter dtype (fp32 master in optimizer)
     # execution --------------------------------------------------------------- #
-    attention_impl: str = "auto"    # auto (ops.choose_flash_impl) |
-                                    # reference | pallas | oracle
     remat: bool = True              # activation checkpointing in train_step
     unroll_layers: bool = False     # materialise the layer loop so
                                     # cost_analysis counts every layer

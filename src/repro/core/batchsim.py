@@ -7,9 +7,7 @@ counts, warmth tier, demotion deadline, queue depth, plus per-cell worker
 free-capacity vectors — and advances EVERY cell of a sweep in lockstep
 with a jit-compiled fixed-timestep driver: ``lax.scan`` over time,
 ``vmap`` over cells, the per-step physics from
-``repro.kernels.ref.cluster_step_ref`` (with a Pallas twin in
-``repro.kernels.cluster_step`` for accelerator runs; parity-tested under
-``interpret=True``).
+``repro.kernels.ref.cluster_step_ref``.
 
 The price of the speed is a *modeling* change, not just an implementation
 one — containers of one function form a cohort sharing one tier and one
@@ -297,10 +295,6 @@ def build_tables(scenarios: Sequence, *, dt: float = DEFAULT_DT,
     K = max([MIN_EDGES] + [len(s) for _, _, _, _, scheds in cells
                            for s in scheds.values()])
     T = max(int(math.ceil(t.horizon / dt)) for _, _, _, t, _ in cells)
-    # pad T so the Pallas chunked-time kernel divides evenly; trailing
-    # steps are past every horizon and no-ops (dt_eff == 0)
-    from repro.kernels.cluster_step import DEFAULT_CHUNK
-    T = int(math.ceil(T / DEFAULT_CHUNK)) * DEFAULT_CHUNK
 
     f32 = np.float32
     nw = np.zeros((C, F, W), f32)
@@ -404,8 +398,8 @@ _SCAN_CACHE: Dict[str, object] = {}
 
 
 def _scan_driver():
-    """jit(scan over T of vmap over cells) of the pure-jnp step — the CPU
-    production path (compiled once per shape)."""
+    """jit(scan over T of vmap over cells) of the pure-jnp step
+    (compiled once per shape)."""
     if "fn" in _SCAN_CACHE:
         return _SCAN_CACHE["fn"]
     import jax
@@ -438,29 +432,17 @@ def _scan_driver():
     return run
 
 
-def run_tables(tables: BatchTables, *, kernel: str = "ref"):
-    """Advance the whole grid; returns ``(nw_final, fs_final, agg)``
-    as numpy.
-
-    ``kernel="ref"``: jitted scan of the pure-jnp step.
-    ``kernel="pallas"``: the chunked-time Pallas kernel from
-    ``repro.kernels.cluster_step`` (interpreted on the CPU backend only).
-    """
+def run_tables(tables: BatchTables):
+    """Advance the whole grid through the jitted scan of the pure-jnp
+    step; returns ``(nw_final, fs_final, agg)`` as numpy."""
     import jax.numpy as jnp
 
-    args = (tables.nw, tables.fs, tables.free, tables.arrivals,
-            tables.conc, tables.fparam, tables.promote, tables.dwell,
-            tables.ntier, tables.frac, tables.scal)
-    if kernel == "pallas":
-        from repro.kernels.cluster_step import cluster_sim_pallas
-        nw, fs, _, agg = cluster_sim_pallas(*args)
-    elif kernel == "ref":
-        now_t = jnp.arange(tables.arrivals.shape[1],
-                           dtype=jnp.float32) * tables.dt
-        nw, fs, _, agg = _scan_driver()(*args[:5], now_t, *args[5:])
-    else:
-        raise ValueError(f"unknown batch kernel {kernel!r}; "
-                         "one of ('ref', 'pallas')")
+    now_t = jnp.arange(tables.arrivals.shape[1],
+                       dtype=jnp.float32) * tables.dt
+    nw, fs, _, agg = _scan_driver()(
+        tables.nw, tables.fs, tables.free, tables.arrivals, tables.conc,
+        now_t, tables.fparam, tables.promote, tables.dwell, tables.ntier,
+        tables.frac, tables.scal)
     return np.asarray(nw), np.asarray(fs), np.asarray(agg)
 
 
@@ -537,7 +519,7 @@ def ledgers_from_agg(tables: BatchTables, nw: np.ndarray, fs: np.ndarray,
 
 
 def simulate_batch(scenarios: Sequence, *, dt: float = DEFAULT_DT,
-                   kernel: str = "ref", cost_model=None,
+                   cost_model=None,
                    trace_fn: Optional[Callable] = None) -> List[BatchLedger]:
     """Run every scenario as one batched JAX program; one
     :class:`BatchLedger` per cell, in input order."""
@@ -549,7 +531,7 @@ def simulate_batch(scenarios: Sequence, *, dt: float = DEFAULT_DT,
                 "run topology scenarios under driver='sim' or 'fleet'")
     tables = build_tables(scenarios, dt=dt, cost_model=cost_model,
                           trace_fn=trace_fn)
-    nw, fs, agg = run_tables(tables, kernel=kernel)
+    nw, fs, agg = run_tables(tables)
     return ledgers_from_agg(tables, nw, fs, agg)
 
 

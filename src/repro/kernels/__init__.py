@@ -1,4 +1,4 @@
-"""TPU Pallas kernels + jnp reference paths (see ops.py)."""
+"""TPU Pallas kernels + jnp paths; ``ops.py`` picks between them."""
 import jax
 
 

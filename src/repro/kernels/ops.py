@@ -1,22 +1,17 @@
-"""Jit-ready kernel entry points.
+"""Jit-ready kernel entry points, and the one rule that picks each path.
 
-Each op has three execution paths:
+* ``flash_attention`` — the Pallas kernel (``flash_attention.py``) for
+  forward-only self-attention on a TPU whose lengths are multiples of its
+  smallest block (``choose_flash_impl``), and everywhere else the chunked
+  online-softmax reference in pure jnp: memory-bounded (it never
+  materialises an O(S^2) score tensor) and GSPMD-shardable, so it is also
+  the path the multi-pod dry-run lowers and the one training takes.
+* ``decode_attention`` and ``ssm_scan`` — memory-light jnp on every
+  platform.
 
-* ``impl="reference"`` — memory-bounded pure-jnp implementation (chunked
-  online-softmax flash attention, two-level SSM scan).  This is the path the
-  multi-pod dry-run lowers (it is GSPMD-shardable and never materialises an
-  O(S^2) score tensor), and what runs in CPU tests/benchmarks.
-* ``impl="pallas"`` — the TPU Pallas kernels (``flash_attention.py``,
-  ``decode_attention.py``, ``ssm_scan.py``) with explicit BlockSpec VMEM
-  tiling.  They compile natively on an accelerator and run through the
-  Pallas interpreter only on the CPU backend (tests).
-* ``impl="oracle"`` — the naive oracles in ``ref.py`` (tests only).
-
-Models ask for ``"auto"`` (the config default): ``choose_flash_impl`` picks
-the path of each full-sequence attention call from what it can see, and
-every other op reads ``"auto"`` as ``"reference"``.
-
-All paths agree to numerical tolerance; see ``tests/test_kernels.py``.
+The expert pass picks its own path the same way (``models/moe.expert_path``);
+no configuration or caller chooses a kernel.  The naive oracles in
+``ref.py`` are what ``tests/test_kernels.py`` compares every path against.
 """
 from __future__ import annotations
 
@@ -25,8 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels import ref as _ref
 
 NEG_INF = -1e30
 REF_CHUNK = 1024        # the reference's q and kv chunk (``_flash_reference``)
@@ -46,15 +39,13 @@ def pick_chunk(s: int, target: int) -> int:
     return max(c, 1)
 
 
-def choose_flash_impl(requested: str, *, train: bool, self_attn: bool,
-                      sq: int, skv: int) -> str:
-    """The flash-attention path for one call.  ``"auto"`` is the Pallas
-    kernel for forward-only (it has no VJP) self-attention on a TPU whose
-    lengths are multiples of its smallest block, and the reference
+def choose_flash_impl(*, train: bool, self_attn: bool, sq: int,
+                      skv: int) -> str:
+    """The flash-attention path for one call: ``"pallas"`` for
+    forward-only (the kernel has no VJP) self-attention on a TPU whose
+    lengths are multiples of its smallest block, and ``"reference"``
     everywhere else: the CPU, training, cross-attention, whisper's
-    1500-frame encoder.  Any other ``requested`` path is kept."""
-    if requested != "auto":
-        return requested
+    1500-frame encoder."""
     from repro.kernels.flash_attention import MIN_BLOCK
     if (jax.default_backend() == "tpu" and not train and self_attn
             and sq % MIN_BLOCK == 0 and skv % MIN_BLOCK == 0):
@@ -118,21 +109,20 @@ def _flash_reference(q, k, v, *, causal, window, q_pos, kv_pos, scale=None,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     q_pos=None, kv_pos=None, scale: Optional[float] = None,
-                    impl: str = "reference"):
+                    train: bool = False, self_attn: bool = True):
     """Blocked attention. q: (B,Sq,Hq,D), k: (B,Skv,Hkv,D), v:
     (B,Skv,Hkv,Dv) -> (B,Sq,Hq,Dv); scores scaled by ``scale`` (default
-    D**-0.5)."""
+    D**-0.5).  ``train`` says a gradient will be taken and ``self_attn``
+    that q and k are one sequence; with the shapes they pick the path
+    (``choose_flash_impl``)."""
     b, sq, hq, d = q.shape
     skv = k.shape[1]
     if q_pos is None:
         q_pos = jnp.arange(sq) + (skv - sq)
     if kv_pos is None:
         kv_pos = jnp.arange(skv)
-    if impl == "oracle":
-        return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                        q_pos=q_pos, kv_pos=kv_pos,
-                                        scale=scale)
-    if impl == "pallas":
+    if choose_flash_impl(train=train, self_attn=self_attn, sq=sq,
+                         skv=skv) == "pallas":
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       q_pos=q_pos, kv_pos=kv_pos, scale=scale)
@@ -145,13 +135,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
 # --------------------------------------------------------------------------- #
 
 
-def decode_attention(q, k_cache, v_cache, valid_mask, *, impl: str = "reference"):
+def decode_attention(q, k_cache, v_cache, valid_mask):
     """q: (B,Hq,D); caches (B,S,Hkv,D); valid_mask (B,S) -> (B,Hq,D)."""
-    if impl == "pallas":
-        from repro.kernels.decode_attention import decode_attention_pallas
-        return decode_attention_pallas(q, k_cache, v_cache, valid_mask)
-    if impl == "oracle":
-        return _ref.decode_attention_ref(q, k_cache, v_cache, valid_mask)
     # memory-light jnp: scores are only (B, Hq, S)
     b, hq, d = q.shape
     hkv = k_cache.shape[2]
@@ -169,14 +154,8 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *, impl: str = "reference"
 # --------------------------------------------------------------------------- #
 
 
-def ssm_scan(u, delta, A, B, C, D, h0, *, chunk: int = 256,
-             impl: str = "reference"):
+def ssm_scan(u, delta, A, B, C, D, h0, *, chunk: int = 256):
     """Mamba-1 selective scan.  See ``ref.ssm_scan_ref`` for semantics."""
-    if impl == "oracle":
-        return _ref.ssm_scan_ref(u, delta, A, B, C, D, h0)
-    if impl == "pallas":
-        from repro.kernels.ssm_scan import ssm_scan_pallas
-        return ssm_scan_pallas(u, delta, A, B, C, D, h0)
     bsz, t, din = u.shape
     n = A.shape[1]
     c = pick_chunk(t, chunk)

@@ -1,10 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel.
+"""Pure-jnp oracles, and the batch simulator's per-step physics.
 
-These are deliberately naive (O(S^2) score materialisation, step-by-step
-scans): they are the *correctness* reference that both the memory-bounded
-jnp implementations in ``ops.py`` and the Pallas TPU kernels are tested
-against (``tests/test_kernels.py`` sweeps shapes/dtypes with
-``assert_allclose``).
+The attention and scan oracles are deliberately naive (O(S^2) score
+materialisation, step-by-step scans): they are the *correctness*
+reference that the memory-bounded jnp paths in ``ops.py`` and the Pallas
+prefill kernel are tested against (``tests/test_kernels.py`` sweeps
+shapes/dtypes with ``assert_allclose``).
 """
 from __future__ import annotations
 
@@ -85,8 +85,8 @@ def decode_attention_ref(q, k_cache, v_cache, valid_mask):
 # batch driver (``repro.core.batchsim``).  Containers of one function are
 # collapsed into a *cohort*: one count per (function, worker), one warmth
 # tier / schedule edge / demotion deadline per function.  All layout
-# constants live here so the Pallas kernel (``kernels/cluster_step.py``),
-# the table builder, and the tests agree on column meanings.
+# constants live here so the step, the table builder, the gym
+# (``repro.learn.gym``) and the tests agree on column meanings.
 #
 # state (per cell, float32 throughout — tiers/edges are small exact ints):
 #   nw   [F, W]   resident containers of function f on worker w
@@ -322,8 +322,8 @@ def cluster_step_full(nw, fs, free, arrivals, conc, now, fparam, promote,
 
 def cluster_step_ref(nw, fs, free, arrivals, conc, now, fparam, promote,
                      dwell, ntier, frac, scal):
-    """Aggregate-only view of :func:`cluster_step_full` — the signature the
-    batch driver and the Pallas twin are parity-tested against."""
+    """Aggregate-only view of :func:`cluster_step_full` — the step the
+    batch driver scans."""
     nw, fs, free, agg, _ = cluster_step_full(
         nw, fs, free, arrivals, conc, now, fparam, promote, dwell, ntier,
         frac, scal)

@@ -55,7 +55,7 @@ def _proj_qkv(p, x, kv_x, h, hkv, hd):
 
 
 def full_attention(p, x, cfg, *, q_pos, causal=True, window=None,
-                   kv_x=None, use_rope=True, impl=None,
+                   kv_x=None, use_rope=True,
                    num_heads=None, num_kv_heads=None, return_kv=False,
                    train=False):
     """Full-sequence attention (train / prefill / encoder / cross).
@@ -75,12 +75,9 @@ def full_attention(p, x, cfg, *, q_pos, causal=True, window=None,
         cos, sin = layers.rope_cos_sin(q_pos, hd, cfg.rope_theta)
         q = layers.apply_rope(q, cos[None], sin[None])
         k = layers.apply_rope(k, cos[None], sin[None])
-    impl = ops.choose_flash_impl(impl or cfg.attention_impl, train=train,
-                                 self_attn=self_attn, sq=q.shape[1],
-                                 skv=k.shape[1])
     out = ops.flash_attention(
         q, k, v, causal=causal and self_attn, window=window,
-        q_pos=q_pos, kv_pos=kv_pos, impl=impl)
+        q_pos=q_pos, kv_pos=kv_pos, train=train, self_attn=self_attn)
     b, sq = x.shape[0], x.shape[1]
     y = out.reshape(b, sq, h * hd) @ p["wo"]
     if return_kv:
@@ -96,8 +93,8 @@ def prefill_attention(cfg, seq: int, window: Optional[int]) -> dict:
     chunk pairs (it computes all); the cache's bytes a token over every
     layer (latent attention keeps the latent and the rotated key).  Span
     attributes of ``engine.start.compile``."""
-    impl = ops.choose_flash_impl(cfg.attention_impl, train=False,
-                                 self_attn=True, sq=seq, skv=seq)
+    impl = ops.choose_flash_impl(train=False, self_attn=True, sq=seq,
+                                 skv=seq)
     if impl == "pallas":
         done, total = flash_attention.block_counts(
             seq, seq, cfg.num_heads, cfg.num_kv_heads, cfg.qk_head_dim,
@@ -126,7 +123,7 @@ def init_cache(cfg, batch: int, max_seq: int, *, window: Optional[int] = None,
 
 
 def decode_attention(p, x, cache, pos, cfg, *, window=None,
-                     cross_kv=None, use_rope=True, impl=None,
+                     cross_kv=None, use_rope=True,
                      num_heads=None, num_kv_heads=None):
     """One-token decode.  x: (B, d); pos: scalar int (current position).
 
@@ -142,7 +139,7 @@ def decode_attention(p, x, cache, pos, cfg, *, window=None,
         k, v = cross_kv
         q = (x @ p["wq"]).reshape(b, h, hd)
         valid = jnp.ones((b, k.shape[1]), bool)
-        out = ops.decode_attention(q, k, v, valid, impl=impl or cfg.attention_impl)
+        out = ops.decode_attention(q, k, v, valid)
         return out.reshape(b, h * hd) @ p["wo"], cache
 
     q, k, v = _proj_qkv(p, x[:, None, :], x[:, None, :], h, hkv, hd)
@@ -165,7 +162,6 @@ def decode_attention(p, x, cache, pos, cfg, *, window=None,
         # full-size cache but windowed attention (jamba @ 32k)
         valid &= idx > (pos - window)
     valid = jnp.broadcast_to(valid[None], (b, s_cache))
-    out = ops.decode_attention(q.reshape(b, h, hd), k_cache, v_cache, valid,
-                               impl=impl or cfg.attention_impl)
+    out = ops.decode_attention(q.reshape(b, h, hd), k_cache, v_cache, valid)
     y = out.reshape(b, h * hd) @ p["wo"]
     return y, {"k": k_cache, "v": v_cache}

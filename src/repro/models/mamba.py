@@ -1,7 +1,7 @@
 """Mamba-1 selective-SSM mixer block (Jamba's SSM half).
 
-Full-sequence path uses ``ops.ssm_scan`` (chunked two-level scan; Pallas
-kernel on TPU); decode is a single recurrence step.  Decode state per layer:
+Full-sequence path uses ``ops.ssm_scan`` (chunked two-level scan);
+decode is a single recurrence step.  Decode state per layer:
 ``conv`` (B, d_conv-1, d_in) trailing inputs + ``h`` (B, d_in, N) fp32 SSM
 state — O(1) in sequence length, which is why hybrid/SSM archs run the
 long_500k shape.
@@ -73,8 +73,7 @@ def mamba_forward(p, x, cfg, *, h0=None):
     dt, bm, cm = _split_xproj(p, xs, cfg)
     A = -jnp.exp(p["A_log"])
     h0 = h0 if h0 is not None else jnp.zeros((b, d_in, s.d_state), jnp.float32)
-    y, hT = ops.ssm_scan(xs, dt, A, bm, cm, p["D"], h0,
-                         impl=cfg.attention_impl if cfg.attention_impl == "pallas" else "reference")
+    y, hT = ops.ssm_scan(xs, dt, A, bm, cm, p["D"], h0)
     y = y * jax.nn.silu(z)
     out = y @ p["out_proj"]
     return out, {"conv": conv_state, "h": hT}

@@ -93,10 +93,9 @@ def mla_full(p, x, cfg, *, q_pos, train=False):
                                                             k_pe.shape[-1]))],
         axis=-1)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    impl = ops.choose_flash_impl(cfg.attention_impl, train=train,
-                                 self_attn=True, sq=s, skv=s)
     o = ops.flash_attention(q, k, kv[..., dn:], causal=True, q_pos=q_pos,
-                            kv_pos=q_pos, scale=softmax_scale(cfg), impl=impl)
+                            kv_pos=q_pos, scale=softmax_scale(cfg),
+                            train=train)
     return o.reshape(b, s, h * dv) @ p["wo"], {"c": c, "pe": k_pe}
 
 

@@ -1,5 +1,5 @@
-"""Batch-driver tests: Pallas-vs-ref kernel parity (randomized fixtures;
-interpreted on the CPU, native on a TPU), batch-vs-scalar tolerance
+"""Batch-driver tests: the jitted scan driver against a per-step loop of
+the reference step (randomized fixtures), batch-vs-scalar tolerance
 spot-checks, unsupported-policy rejection, the runner's batch plumbing
 (summary schema, events= rejection, ``max_cells`` guard, progress
 callbacks), and the trace-cache LRU regression."""
@@ -14,7 +14,6 @@ from repro.experiments import runner
 from repro.experiments.spec import ClusterSpec, Scenario, WorkloadSpec
 from repro.experiments.sweep import Sweep
 from repro.kernels import ref as R
-from repro.kernels.cluster_step import cluster_sim_pallas
 
 
 def _cell(name="t/batch", *, rate=8.0, horizon=60.0, fns=4, seed=3,
@@ -30,10 +29,10 @@ def _cell(name="t/batch", *, rate=8.0, horizon=60.0, fns=4, seed=3,
 
 
 # --------------------------------------------------------------------------- #
-# Pallas kernel vs pure-jnp reference driver
+# the jitted scan driver vs a per-step loop of the reference step
 # --------------------------------------------------------------------------- #
 def _random_tables(rng, *, C=3, F=4, W=2, K=4, T=16):
-    """Randomized array-state in the kernel's own layout: arrivals with
+    """Randomized array-state in the step's own layout: arrivals with
     bursts, mixed tiers/edges/deadlines, partially-used workers."""
     f32 = np.float32
     nw = (rng.integers(0, 3, (C, F, W))).astype(f32)
@@ -67,7 +66,8 @@ def _random_tables(rng, *, C=3, F=4, W=2, K=4, T=16):
 
 
 def _ref_drive(nw, fs, free, arrivals, conc, fparam, promote, dwell,
-               ntier, frac, scal):
+               ntier, frac, scal, dt=0.5):
+    """One eager call of the vmapped reference step per time step."""
     import jax
     import jax.numpy as jnp
 
@@ -78,41 +78,40 @@ def _ref_drive(nw, fs, free, arrivals, conc, fparam, promote, dwell,
     nw, fs, free = jnp.asarray(nw), jnp.asarray(fs), jnp.asarray(free)
     for t in range(T):
         nw, fs, free, d = step(nw, fs, free, arrivals[:, t], conc[:, t],
-                               jnp.float32(t * 0.5), fparam, promote,
+                               jnp.float32(t * dt), fparam, promote,
                                dwell, ntier, frac, scal)
         agg = agg + d
     return map(np.asarray, (nw, fs, free, agg))
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_pallas_matches_ref_on_random_state(seed):
+def test_scan_driver_matches_stepwise_ref_on_random_state(seed):
+    import jax.numpy as jnp
+
     rng = np.random.default_rng(seed)
     (nw, fs, free, arrivals, conc, promote, dwell, ntier, frac, scal,
      fparam) = _random_tables(rng)
     ref = list(_ref_drive(nw, fs, free, arrivals, conc, fparam, promote,
                           dwell, ntier, frac, scal))
-    pal = cluster_sim_pallas(nw, fs, free, arrivals, conc, fparam, promote,
-                             dwell, ntier, frac, scal, chunk=8)
-    for name, a, b in zip(("nw", "fs", "free", "agg"), ref, pal):
+    now_t = jnp.arange(arrivals.shape[1], dtype=jnp.float32) * 0.5
+    got = batchsim._scan_driver()(nw, fs, free, arrivals, conc, now_t,
+                                  fparam, promote, dwell, ntier, frac, scal)
+    for name, a, b in zip(("nw", "fs", "free", "agg"), ref, got):
         np.testing.assert_allclose(np.asarray(b), a, rtol=1e-4, atol=1e-2,
-                                   err_msg=f"pallas/{name} diverged")
+                                   err_msg=f"scan/{name} diverged")
 
 
-def test_pallas_matches_ref_on_built_tables():
+def test_run_tables_matches_stepwise_ref_on_built_tables():
     cells = [_cell(seed=s, ttl=ttl)
              for s, ttl in ((1, 20.0), (2, None), (3, 90.0))]
-    tables = build_tables(cells)
-    ref = run_tables(tables, kernel="ref")
-    pal = run_tables(tables, kernel="pallas")
-    for name, a, b in zip(("nw", "fs", "agg"), ref, pal):
+    t = build_tables(cells)
+    nw, fs, _, agg = _ref_drive(t.nw, t.fs, t.free, t.arrivals, t.conc,
+                                t.fparam, t.promote, t.dwell, t.ntier,
+                                t.frac, t.scal, dt=t.dt)
+    for name, a, b in zip(("nw", "fs", "agg"), (nw, fs, agg),
+                          run_tables(t)):
         np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-2,
-                                   err_msg=f"pallas/{name} diverged")
-
-
-def test_unknown_kernel_rejected():
-    tables = build_tables([_cell()])
-    with pytest.raises(ValueError, match="unknown batch kernel"):
-        run_tables(tables, kernel="tpu")
+                                   err_msg=f"scan/{name} diverged")
 
 
 # --------------------------------------------------------------------------- #

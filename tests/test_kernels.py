@@ -1,17 +1,18 @@
 """Kernel validation: Pallas (interpreted on the CPU, native on a TPU)
 and the memory-bounded jnp paths vs the naive oracles in
 ``kernels/ref.py`` — shape/dtype sweeps with assert_allclose (assignment
-requirement)."""
+requirement) — and the rule that picks each program's kernels."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.config import ARCH_IDS, canonical_arch_id
 from repro.kernels import ops, ref
 from repro.models import registry
-from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import block_counts, flash_attention_pallas
-from repro.kernels.ssm_scan import ssm_scan_pallas
 
 RNG = np.random.default_rng(42)
 
@@ -49,8 +50,9 @@ def test_flash_attention_matches_oracle(case, dtype):
                                    scale=scale)
     got_pallas = flash_attention_pallas(q, k, v, causal=causal, window=window,
                                         scale=scale)
-    got_ref = ops.flash_attention(q, k, v, causal=causal, window=window,
-                                  scale=scale, impl="reference")
+    got_ref = ops._flash_reference(q, k, v, causal=causal, window=window,
+                                   q_pos=jnp.arange(sq) + (skv - sq),
+                                   kv_pos=jnp.arange(skv), scale=scale)
     np.testing.assert_allclose(np.asarray(got_pallas, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
     np.testing.assert_allclose(np.asarray(got_ref, np.float32),
@@ -74,11 +76,8 @@ def test_decode_attention_matches_oracle(case, dtype):
     v = jnp.asarray(RNG.normal(size=(b, s, hkv, d)), dtype)
     mask = jnp.asarray(RNG.random((b, s)) > 0.25)
     want = ref.decode_attention_ref(q, k, v, mask)
-    got_p = decode_attention_pallas(q, k, v, mask)
-    got_r = ops.decode_attention(q, k, v, mask, impl="reference")
-    np.testing.assert_allclose(np.asarray(got_p, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
-    np.testing.assert_allclose(np.asarray(got_r, np.float32),
+    got = ops.decode_attention(q, k, v, mask)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
@@ -100,12 +99,11 @@ def test_ssm_scan_matches_oracle(case):
     Dm = jnp.asarray(RNG.normal(size=(din,)), jnp.float32)
     h0 = jnp.asarray(RNG.normal(size=(bt, din, n)), jnp.float32)
     want_y, want_h = ref.ssm_scan_ref(u, dt, A, Bm, Cm, Dm, h0)
-    got_y, got_h = ssm_scan_pallas(u, dt, A, Bm, Cm, Dm, h0)
+    # a chunk shorter than the sequence, so the two-level scan carries
+    # its state across chunks
+    got_y, got_h = ops.ssm_scan(u, dt, A, Bm, Cm, Dm, h0, chunk=64)
     np.testing.assert_allclose(got_y, want_y, atol=5e-5, rtol=5e-5)
     np.testing.assert_allclose(got_h, want_h, atol=5e-5, rtol=5e-5)
-    ref_y, ref_h = ops.ssm_scan(u, dt, A, Bm, Cm, Dm, h0, impl="reference")
-    np.testing.assert_allclose(ref_y, want_y, atol=5e-5, rtol=5e-5)
-    np.testing.assert_allclose(ref_h, want_h, atol=5e-5, rtol=5e-5)
 
 
 def test_ssm_step_matches_scan():
@@ -183,12 +181,8 @@ CHOICES = [
 def test_choose_flash_impl(monkeypatch, case):
     backend, train, self_attn, seq, want = case
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert ops.choose_flash_impl("auto", train=train, self_attn=self_attn,
-                                 sq=seq, skv=seq) == want
-    for explicit in ("reference", "pallas", "oracle"):
-        assert ops.choose_flash_impl(explicit, train=train,
-                                     self_attn=self_attn, sq=seq,
-                                     skv=seq) == explicit
+    assert ops.choose_flash_impl(train=train, self_attn=self_attn, sq=seq,
+                                 skv=seq) == want
 
 
 def test_prefill_attention_span_attributes(monkeypatch):
@@ -223,20 +217,27 @@ def test_latent_prefill_attention_span_attributes(monkeypatch):
         **cache}
 
 
-def _pallas_calls(fn, *args):
+def _kernels(fn, *args):
+    """The names of the Pallas kernels ``fn``'s program calls, one per
+    call site."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
     # a fresh closure, so that no earlier trace of ``fn`` is reused
-    return str(jax.make_jaxpr(lambda *a: fn(*a))(*args)).count("pallas_call")
+    return list(walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr))
 
 
 def test_auto_attention_path_by_platform_and_gradient(monkeypatch):
-    """``"auto"``: the CPU keeps the reference everywhere; on a TPU only
-    prefill takes the kernel, one call per layer scan, and the loss's
-    gradient still runs (through the reference)."""
+    """The CPU keeps the reference everywhere; on a TPU only prefill takes
+    the kernel, one call per layer scan, and the loss's gradient still
+    runs (through the reference)."""
     from repro.models import lm
 
     bundle = registry.build_arch("granite-3-2b", smoke=True, max_seq=128)
     cfg = bundle.cfg
-    assert cfg.attention_impl == "auto"
     params = bundle.init(jax.random.key(0))
     tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size, (1, 128)), jnp.int32)
     batch = {"tokens": tokens, "labels": tokens}
@@ -244,12 +245,59 @@ def test_auto_attention_path_by_platform_and_gradient(monkeypatch):
     def loss(p):
         return lm.lm_loss(p, cfg, batch)[0]
 
-    assert _pallas_calls(bundle.prefill, params, batch) == 0
+    assert not _kernels(bundle.prefill, params, batch)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert _pallas_calls(bundle.prefill, params, batch) == 1
-    assert _pallas_calls(jax.grad(loss), params) == 0
+    assert len(_kernels(bundle.prefill, params, batch)) == 1
+    assert not _kernels(jax.grad(loss), params)
     grads = jax.grad(loss)(params)
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_program_kernels_by_platform(arch, monkeypatch):
+    """The platform and the shapes alone pick each kernel: a smoke
+    bundle's prefill and decode step (batch 1, a 128-token prompt) call no
+    Pallas kernel on the CPU; on a TPU prefill calls the flash kernel
+    exactly when ``ops.choose_flash_impl`` chose it for one of its
+    attention calls, and decode calls only the expert kernel, for a model
+    whose decode pairs ``moe.expert_path`` sends there."""
+    from repro.models import moe
+
+    cfg = importlib.import_module(
+        f"repro.configs.{canonical_arch_id(arch)}").SMOKE
+    bundle = registry.build(cfg, max_seq=128 + 8)
+    params = jax.eval_shape(bundle.init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 128), jnp.int32)}
+    if cfg.encoder is not None:
+        batch["frames"] = jax.ShapeDtypeStruct(
+            (1, cfg.encoder.num_frames, cfg.encoder.d_model), cfg.dtype)
+    if cfg.vision is not None:
+        batch["image_embeds"] = jax.ShapeDtypeStruct(
+            (1, cfg.vision.num_image_tokens, cfg.vision.d_embed), cfg.dtype)
+    caches = jax.eval_shape(lambda p, b: bundle.prefill(p, b)[1],
+                            params, batch)
+    step = (params, caches, jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            {} if cfg.moe is None else jax.eval_shape(moe.zero_counts))
+    rule = ops.choose_flash_impl
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        chosen = []
+        monkeypatch.setattr(ops, "choose_flash_impl",
+                            lambda **kw: chosen.append(rule(**kw)) or
+                            chosen[-1])
+        prefill = set(_kernels(bundle.prefill, params, batch))
+        decode = set(_kernels(moe.counted_decode(bundle.decode_step),
+                                  *step))
+        experts = cfg.moe is not None and moe.expert_path(
+            cfg.moe.top_k, cfg.moe.held) == "pallas"
+        assert prefill == ({"prefill_flash_attention"}
+                           if "pallas" in chosen else set())
+        assert decode == ({"moe_held_experts"} if experts else set())
+        if backend == "cpu":
+            assert not prefill and not decode
+        elif "A" in cfg.layer_pattern:
+            assert "pallas" in chosen
 
 
 # --------------------------------------------------------------------------- #
@@ -390,7 +438,7 @@ def test_moe_decode_kernel_in_a_deepseek_decode_step(unroll, monkeypatch):
     args = (params, caches, tokens[:, 16], jnp.asarray(pos, jnp.int32),
             moe.zero_counts())
     want, _, dense = jax.jit(step)(*args)
-    assert _pallas_calls(step, *args) == 0
+    assert not _kernels(step, *args)
     _on_the_kernel(monkeypatch)
     got, _, kernel = jax.jit(moe.counted_decode(bundle.decode_step))(*args)
     scale = float(jnp.abs(want).max())
@@ -404,7 +452,7 @@ def test_moe_decode_kernel_in_a_deepseek_decode_step(unroll, monkeypatch):
     assert int(kernel["moe_pairs_held"]) == int(dense["moe_pairs_held"]) > 0
     assert 0 < int(kernel["moe_experts_read"]) <= int(
         kernel["moe_pairs_held"])
-    assert _pallas_calls(moe.counted_decode(bundle.decode_step), *args) > 0
+    assert _kernels(moe.counted_decode(bundle.decode_step), *args)
 
 
 def test_expert_path_by_platform_and_pairs(monkeypatch):

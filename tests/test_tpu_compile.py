@@ -5,8 +5,9 @@ for a *described* v5e and refuses what the chip would refuse (misaligned
 Pallas blocks, primitives Mosaic cannot lower, programs that do not fit
 HBM).  These tests compile the serving path at granite-3-2b's and
 DeepSeek-V2-Lite's published widths, the batch simulator's device program
-at ``batch_dense64`` shapes, and every Pallas kernel at real widths
-natively (``interpret=False``).
+at ``batch_dense64`` shapes, and both Pallas kernels (prefill flash
+attention, the decode expert pass) at real widths natively
+(``interpret=False``).
 
 Nothing runs, so these say nothing about results or times.  The topology
 is described inside a fixture, never at import: only one process may load
@@ -168,16 +169,6 @@ def _flash_mla():
              ((1, 8192, 16, 128), bf)])
 
 
-def _decode():
-    from repro.kernels.decode_attention import decode_attention_pallas
-
-    bf = jnp.bfloat16
-    return (lambda q, k, v, m: decode_attention_pallas(q, k, v, m,
-                                                        interpret=False),
-            [((1, 32, 64), bf), ((1, 2048, 8, 64), bf),
-             ((1, 2048, 8, 64), bf), ((1, 2048), jnp.bool_)])
-
-
 def _moe_decode():
     from repro.kernels.moe_decode import held_experts_pallas
 
@@ -190,31 +181,9 @@ def _moe_decode():
              ((26, 16, 2048, 1408), bf)])
 
 
-def _ssm():
-    from repro.kernels.ssm_scan import ssm_scan_pallas
-
-    f32 = jnp.float32       # jamba-v0.1: d_inner 2 x 4096, d_state 16
-    din, n, t = 8192, 16, 256
-    return (lambda *a: ssm_scan_pallas(*a, interpret=False),
-            [((1, t, din), f32), ((1, t, din), f32), ((din, n), f32),
-             ((1, t, n), f32), ((1, t, n), f32), ((din,), f32),
-             ((1, din, n), f32)])
-
-
-def _cluster():
-    from repro.kernels.cluster_step import cluster_sim_pallas
-
-    c, shapes = _batch_dense64_shapes()
-    order = ("nw", "fs", "free", "arrivals", "conc", "fparam", "promote",
-             "dwell", "ntier", "frac", "scal")
-    return (lambda *a: cluster_sim_pallas(*a, interpret=False),
-            [((c, *shapes[k]), jnp.float32) for k in order])
-
-
-@pytest.mark.parametrize("kernel", [_flash, _flash_danube, _decode, _ssm,
-                                    _cluster, _flash_mla, _moe_decode],
+@pytest.mark.parametrize("kernel", [_flash, _flash_danube, _flash_mla,
+                                    _moe_decode],
                          ids=["flash_attention", "flash_attention_danube",
-                              "decode_attention", "ssm_scan", "cluster_step",
                               "flash_attention_mla", "moe_decode"])
 def test_pallas_kernel_compiles_natively(one_chip, kernel):
     fn, arg_shapes = kernel()
